@@ -2,13 +2,27 @@
 
 Everything in this package operates on int64 coefficient arrays in RNS
 (residue-number-system) representation; 64-bit mode is required for exact
-modular arithmetic on CPU/TPU-interpret backends.
+modular arithmetic.
+
+Importing it also fixes where compiled programs persist, once for every
+entry point (CLIs, benchmarks, chip_smoke.py all import this package):
+`JAX_COMPILATION_CACHE_DIR` when set (JAX reads it itself), otherwise
+`.jax_cache/` at the root of the checkout — a fixed, git-ignored path,
+so a later run of the same code finds its programs again.
 """
+import os
+import pathlib
+
 import jax
 
 # Exact mod-q arithmetic needs 64-bit integers. Model code pins its own
 # dtypes explicitly, so enabling x64 here is safe for the whole package.
 jax.config.update("jax_enable_x64", True)
+
+if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update(
+        "jax_compilation_cache_dir",
+        str(pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"))
 
 # NOTE: functions named like their submodule (encrypt.encrypt,
 # compare.compare) are deliberately NOT re-exported — rebinding them here

@@ -47,17 +47,41 @@ def ct_sub(rng: R.Ring, a: Ciphertext, b: Ciphertext) -> Ciphertext:
     return Ciphertext(R.sub(rng, a.c0, b.c0), R.sub(rng, a.c1, b.c1))
 
 
-def eval_value(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext) -> jax.Array:
-    """Centered integer eval value ≈ scale*Δ_enc*(m0-m1) + noise.  [...]."""
+def keyed_coeff0(ks: KeySet, c1: jax.Array) -> jax.Array:
+    """Coefficient 0 of the CEK product of c1 [..., K, n] -> [..., K].
+
+    paper : coeff0 of c1 ⊛ cek
+    gadget: coeff0 of Σ_e digit_e(c1) ⊛ cek_e   (= gadget_keymul's)
+
+    Decode reads coefficient 0 only, and coefficient 0 of a negacyclic
+    product is one inner product with the key's reversal `ks.cek_rev`,
+    so no NTT runs.  Gadget terms are digit (< 2^8) × residue (< 2^31),
+    and E·n of them sum below 2^63 (2^54 at paper-bfv), so one
+    reduction ends the sum; paper-mode terms are reduced products."""
     params, rng = ks.params, ks.ring
-    d = ct_sub(rng, ct0, ct1)                                  # Alg.2 line 2
-    scaled = R.scalar_mul(rng, d.c0, params.scale)             # line 3a
+    q, mu = rng.q_arr[:, 0], rng.mu_arr[:, 0]
     if params.mode == "paper":
-        keyed = R.negacyclic_mul(rng, d.c1, ks.cek)            # line 3b
-    else:
-        keyed = gadget.gadget_keymul(ks, d.c1)
-    ct_eval = R.add(rng, scaled, keyed)
-    coeff0 = ct_eval[..., :, 0]                                # line 4 Decode
+        terms = R.pointwise_mul(rng, c1, ks.cek_rev)            # [..., K, n]
+        return R.reduce(jnp.sum(terms, axis=-1), q, mu)
+    n = params.n
+    digits = gadget.digit_decompose(params, c1)                # [..., K, D, n]
+    digits = digits.reshape(digits.shape[:-3] + (-1, n))       # [..., E, n]
+    s = jnp.sum(digits[..., :, None, :] * ks.cek_rev, axis=(-3, -1))
+    return R.reduce(s, q, mu)
+
+
+def eval_value(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext) -> jax.Array:
+    """Centered integer eval value ≈ scale*Δ_enc*(m0-m1) + noise.  [...].
+
+    Alg. 2: ctΔ = ct0 - ct1 (line 2), ct_eval = scale·ctΔ,0 + key
+    product of ctΔ,1 (line 3), decode coefficient 0 (line 4) — computed
+    on coefficient 0 alone (`keyed_coeff0`)."""
+    params, rng = ks.params, ks.ring
+    q, mu = rng.q_arr[:, 0], rng.mu_arr[:, 0]
+    d0 = R.submod(ct0.c0[..., :, 0], ct1.c0[..., :, 0], q)    # line 2
+    d1 = R.sub(rng, ct0.c1, ct1.c1)
+    scaled = R.mulmod(d0, params.scale, q, mu)                 # line 3
+    coeff0 = R.addmod(scaled, keyed_coeff0(ks, d1), q)         # line 4
     return R.crt_centered(params, coeff0)
 
 
@@ -204,8 +228,23 @@ def _compare_swap(ks: KeySet, cmp: Callable, c0: jax.Array, c1: jax.Array,
     """One batched compare-exchange stage over index pairs (lo[i], hi[i]).
 
     asc[i] True  => the smaller plaintext lands at lo[i] (ascending pair);
-    asc[i] False => the larger lands at lo[i].  ONE batched Eval per call.
+    asc[i] False => the larger lands at lo[i].  ONE batched Eval per call,
+    and one launch: the stage is a jitted program per (KeySet,
+    comparator), so its gathers and scatters fuse instead of each
+    holding a column-sized intermediate.
     """
+    cache = ks.cache("swap_stage")
+    stage = cache.get(cmp)
+    if stage is None:
+        stage = cache[cmp] = jax.jit(
+            lambda *args: _compare_swap_stage(ks, cmp, *args))
+    return stage(c0, c1, perm, jnp.asarray(lo), jnp.asarray(hi),
+                 jnp.asarray(asc))
+
+
+def _compare_swap_stage(ks: KeySet, cmp: Callable, c0, c1, perm, lo, hi,
+                        asc):
+    """`_compare_swap`'s body (traced once per stage shape)."""
     a = Ciphertext(c0[lo], c1[lo])
     b = Ciphertext(c0[hi], c1[hi])
     a_gt_b = cmp(ks, a, b)                                  # [pairs] bool

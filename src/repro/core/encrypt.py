@@ -53,8 +53,17 @@ def _encrypt_payload(ks: KeySet, payload: jax.Array,
     e0 = sampling.noise_poly(params, k_e0, batch)
     e1 = sampling.noise_poly(params, k_e1, batch)
     m_poly = R.const_poly(params, payload)             # [..., K, n]
-    c0 = R.add(rng, R.add(rng, R.negacyclic_mul(rng, ks.pk0, u), e0), m_poly)
-    c1 = R.add(rng, R.negacyclic_mul(rng, ks.pk1, u), e1)
+    # pk0*u and pk1*u share u's forward transform and one inverse
+    # transform over both products
+    pk_ntt = ks.pk_ntt
+    if pk_ntt is None:
+        pk_ntt = R.ntt(rng, jnp.stack([ks.pk0, ks.pk1]))
+    u_ntt = R.ntt(rng, u)
+    prods = R.intt(rng, R.pointwise_mul(
+        rng, pk_ntt.reshape((2,) + (1,) * len(batch) + pk_ntt.shape[1:]),
+        u_ntt[None]))                                  # [2, ..., K, n]
+    c0 = R.add(rng, R.add(rng, prods[0], e0), m_poly)
+    c1 = R.add(rng, prods[1], e1)
     return Ciphertext(c0=c0, c1=c1)
 
 

@@ -53,6 +53,6 @@ def gadget_keymul(ks: KeySet, c1: jax.Array) -> jax.Array:
     dig_ntt = R.ntt(rng, flat)                           # [..., K*D, K, n]
 
     cek_ntt = ks.cek_gadget_ntt.reshape(K * D, K, n)     # [K*D, K, n]
-    prod = (dig_ntt * cek_ntt) % rng.q_arr               # eval domain
-    acc = jnp.sum(prod, axis=-3) % rng.q_arr             # [..., K, n]
+    prod = R.pointwise_mul(rng, dig_ntt, cek_ntt)        # eval domain
+    acc = R.reduce(jnp.sum(prod, axis=-3), rng.q_arr, rng.mu_arr)  # [..., K, n]
     return R.intt(rng, acc)

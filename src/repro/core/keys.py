@@ -36,10 +36,23 @@ class KeySet:
     cek: Optional[jax.Array]           # paper mode: [K, n]
     cek_gadget: Optional[jax.Array]    # gadget mode: [K_src, D, K, n]
     cek_gadget_ntt: Optional[jax.Array]  # same, eval domain (precomputed)
+    # coefficient-0 form of the CEK (`ring.coeff0_reversal`): paper
+    # [K, n], gadget [K_src*D, K, n].  Eval reads one coefficient of the
+    # key product, an inner product against this.
+    cek_rev: Optional[jax.Array] = None
+    # eval-domain public key [2, K, n] (pk0, pk1): encryption transforms
+    # only its randomness
+    pk_ntt: Optional[jax.Array] = None
 
     @property
     def mode(self) -> str:
         return self.params.mode
+
+    def cache(self, name: str) -> dict:
+        """A dict stashed on this keyset, for jitted programs that close
+        over it (their lifetime is the keyset's): the one per-KeySet
+        cache every layer uses."""
+        return self.__dict__.setdefault(f"_cache_{name}", {})
 
 
 def _gadget_cek(params: HadesParams, rng: R.Ring, sk: jax.Array,
@@ -67,7 +80,7 @@ def _gadget_cek(params: HadesParams, rng: R.Ring, sk: jax.Array,
                 np.asarray([c % q for q in params.qs], dtype=np.int64)
             )[:, None]                                   # [K, 1]
             e = sampling.noise_poly(params, keys[k_src * D + j])
-            entry = ((sk * c_rns) % rng.q_arr + e) % rng.q_arr
+            entry = R.add(rng, R.pointwise_mul(rng, sk, c_rns), e)
             entries.append(entry)
     return jnp.stack(entries).reshape(K, D, K, n)
 
@@ -100,13 +113,16 @@ def keygen(params: HadesParams, key: jax.Array,
             e_cek = e_cek * mask
         sk_scaled = R.scalar_mul(rng, sk, params.scale)            # line 7
         cek = R.add(rng, sk_scaled, e_cek)                         # line 8
+        cek_rev = R.coeff0_reversal(rng, cek)
     else:
         cek_gadget = _gadget_cek(params, rng, sk, k_g)
         # Precompute the eval-domain form: Eval does (digit ⊛ cek) products,
         # so keeping cek in NTT form saves one forward NTT per entry/compare.
         flat = cek_gadget.reshape(-1, params.num_towers, params.n)
         cek_gadget_ntt = R.ntt(rng, flat).reshape(cek_gadget.shape)
+        cek_rev = R.coeff0_reversal(rng, flat)
 
     return KeySet(params=params, ring=rng, sk=sk, pk0=pk0, pk1=a,
                   cek=cek, cek_gadget=cek_gadget,
-                  cek_gadget_ntt=cek_gadget_ntt)
+                  cek_gadget_ntt=cek_gadget_ntt, cek_rev=cek_rev,
+                  pk_ntt=R.ntt(rng, jnp.stack([pk0, a])))

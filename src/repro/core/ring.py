@@ -1,9 +1,16 @@
 """R_q = Z_q[x]/(x^n + 1) arithmetic in RNS form, pure JAX.
 
 Polynomials are int64 arrays of shape [..., K, n] (K = number of RNS towers),
-with residues kept in [0, q_k).  All products of two residues fit a signed
-int64 (q_k < 2^31), so `%` gives exact modular arithmetic on CPU and in
-Pallas interpret mode.  This module is also the *reference oracle* for the
+with residues kept in [0, q_k).  Every tower modulus is a prime in
+(2^30, 2^31), so a product of two residues fits a signed int64.
+
+This module owns modular arithmetic, and it never divides: an int64 `%`
+is a runtime 64-bit division, which the TPU emulates, and its expansion
+costs the TPU compiler about a second per op (a compare program held
+hundreds of them).  Sums reduce by one conditional subtract, products by
+Barrett reduction against mu = floor(2^62 / q) (`reduce`), and CRT
+reconstruction runs in Garner form.  The results are the same canonical
+residues `%` gives.  This module is also the *reference oracle* for the
 Pallas NTT kernels (kernels/ref.py re-exports it).
 
 The NTT is the standard negacyclic transform: pre-twist by psi^i, DIT
@@ -19,6 +26,65 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.params import HadesParams, NttTables
+
+
+# ---------------------------------------------------------------------------
+# division-free modular arithmetic (q, mu broadcast against x).  Each
+# helper is jitted: under an outer jit it inlines, and called eagerly
+# (keygen, client-side encryption) it is one program per shape rather
+# than one per primitive.
+# ---------------------------------------------------------------------------
+
+def barrett_mu(qs) -> np.ndarray:
+    """Barrett constants floor(2^62 / q) for moduli in (2^30, 2^31)."""
+    qs = [int(q) for q in np.ravel(np.asarray(qs, dtype=object))]
+    if not all((1 << 30) < q < (1 << 31) for q in qs):
+        raise ValueError(f"tower moduli must lie in (2^30, 2^31): {qs}")
+    return np.asarray([(1 << 62) // q for q in qs], dtype=np.int64)
+
+
+@jax.jit
+def csub(x: jax.Array, q) -> jax.Array:
+    """x in [0, 2q) -> x mod q."""
+    return jnp.where(x >= q, x - q, x)
+
+
+@jax.jit
+def reduce(x: jax.Array, q, mu) -> jax.Array:
+    """x in [0, 2^62) -> x mod q, with mu = floor(2^62 / q).
+
+    The quotient estimate floor((x >> 31) * mu / 2^31) never exceeds
+    x / q and falls short of it by less than 4 (q > 2^30), so the
+    remainder lands in [0, 4q) and three conditional subtracts finish.
+    (x >> 31) * mu < 2^31 * 2^32 stays inside int64."""
+    r = x - (((x >> 31) * mu) >> 31) * q
+    for _ in range(3):
+        r = csub(r, q)
+    return r
+
+
+@jax.jit
+def reduce_signed(x: jax.Array, q, mu) -> jax.Array:
+    """x with |x| < 2^62 -> x mod q in [0, q)."""
+    r = reduce(jnp.abs(x), q, mu)
+    return jnp.where((x < 0) & (r != 0), q - r, r)
+
+
+@jax.jit
+def mulmod(a: jax.Array, b, q, mu) -> jax.Array:
+    """a * b mod q for residues a, b in [0, q)."""
+    return reduce(a * b, q, mu)
+
+
+@jax.jit
+def addmod(a: jax.Array, b: jax.Array, q) -> jax.Array:
+    return csub(a + b, q)
+
+
+@jax.jit
+def submod(a: jax.Array, b: jax.Array, q) -> jax.Array:
+    d = a - b
+    return jnp.where(d < 0, d + q, d)
 
 
 @jax.tree_util.register_dataclass
@@ -38,6 +104,7 @@ class Ring:
     stage_w: jax.Array = None        # [K, S, n/2]
     stage_w_inv: jax.Array = None    # [K, S, n/2]
     bitrev: jax.Array = None         # [n]
+    mu_arr: jax.Array = None         # [K, 1] Barrett constants
 
     @property
     def num_towers(self) -> int:
@@ -59,32 +126,33 @@ def make_ring(params: HadesParams) -> Ring:
         stage_w=jnp.asarray(t.stage_w),
         stage_w_inv=jnp.asarray(t.stage_w_inv),
         bitrev=jnp.asarray(t.bitrev),
+        mu_arr=jnp.asarray(barrett_mu(params.qs)[:, None]),
     )
 
 
 # ---------------------------------------------------------------------------
-# elementwise ring ops
+# elementwise ring ops (operands are canonical residues [..., K, n])
 # ---------------------------------------------------------------------------
 
 def add(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
-    return (a + b) % ring.q_arr
+    return addmod(a, b, ring.q_arr)
 
 
 def sub(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
-    return (a - b) % ring.q_arr
+    return submod(a, b, ring.q_arr)
 
 
 def neg(ring: Ring, a: jax.Array) -> jax.Array:
-    return (-a) % ring.q_arr
+    return jnp.where(a == 0, a, ring.q_arr - a)
 
 
 def scalar_mul(ring: Ring, a: jax.Array, s: jax.Array | int) -> jax.Array:
-    """a * s mod q, s an int64 scalar already reduced below 2^31."""
-    return (a * jnp.int64(s)) % ring.q_arr
+    """a * s mod q, s an int64 scalar already reduced below 2^30."""
+    return mulmod(a, jnp.int64(s), ring.q_arr, ring.mu_arr)
 
 
 def pointwise_mul(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
-    return (a * b) % ring.q_arr
+    return mulmod(a, b, ring.q_arr, ring.mu_arr)
 
 
 # ---------------------------------------------------------------------------
@@ -92,9 +160,10 @@ def pointwise_mul(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
 # ---------------------------------------------------------------------------
 
 def _dit_stages(a: jax.Array, stage_w: jax.Array, q: jax.Array,
-                n: int) -> jax.Array:
+                mu: jax.Array, n: int) -> jax.Array:
     """Forward DIT butterflies on bit-reversed input. a: [..., K, n]."""
     stages = n.bit_length() - 1
+    q, mu = q[..., None], mu[..., None]
     for s in range(stages):
         h = 1 << s
         m = h * 2
@@ -102,17 +171,17 @@ def _dit_stages(a: jax.Array, stage_w: jax.Array, q: jax.Array,
         x = a.reshape(a.shape[:-1] + (n // m, m))
         u = x[..., :h]                             # [..., K, n/m, h]
         v = x[..., h:]
-        t = (v * w[:, None, :]) % q[..., None]
-        a = jnp.concatenate([(u + t) % q[..., None],
-                             (u - t) % q[..., None]], axis=-1)
+        t = mulmod(v, w[:, None, :], q, mu)
+        a = jnp.concatenate([addmod(u, t, q), submod(u, t, q)], axis=-1)
         a = a.reshape(a.shape[:-2] + (n,))
     return a
 
 
 def _gs_stages(a: jax.Array, stage_w_inv: jax.Array, q: jax.Array,
-               n: int) -> jax.Array:
+               mu: jax.Array, n: int) -> jax.Array:
     """Inverse Gentleman-Sande butterflies, natural-order input."""
     stages = n.bit_length() - 1
+    q, mu = q[..., None], mu[..., None]
     for s in reversed(range(stages)):
         h = 1 << s
         m = h * 2
@@ -120,32 +189,43 @@ def _gs_stages(a: jax.Array, stage_w_inv: jax.Array, q: jax.Array,
         x = a.reshape(a.shape[:-1] + (n // m, m))
         u = x[..., :h]
         v = x[..., h:]
-        a = jnp.concatenate([(u + v) % q[..., None],
-                             ((u - v) * w[:, None, :]) % q[..., None]],
+        a = jnp.concatenate([addmod(u, v, q),
+                             mulmod(submod(u, v, q), w[:, None, :], q, mu)],
                             axis=-1)
         a = a.reshape(a.shape[:-2] + (n,))
     return a
 
 
+@jax.jit
 def ntt(ring: Ring, a: jax.Array) -> jax.Array:
     """Negacyclic forward NTT. a: [..., K, n] -> [..., K, n] (eval domain)."""
-    q = ring.q_arr  # [K, 1]
-    a = (a * ring.psi_pow) % q            # pre-twist
+    a = pointwise_mul(ring, a, ring.psi_pow)           # pre-twist
     a = jnp.take(a, ring.bitrev, axis=-1)
-    return _dit_stages(a, ring.stage_w, q, ring.n)
+    return _dit_stages(a, ring.stage_w, ring.q_arr, ring.mu_arr, ring.n)
 
 
+@jax.jit
 def intt(ring: Ring, a: jax.Array) -> jax.Array:
     """Negacyclic inverse NTT (includes n^-1 scaling)."""
-    q = ring.q_arr
-    a = _gs_stages(a, ring.stage_w_inv, q, ring.n)
+    a = _gs_stages(a, ring.stage_w_inv, ring.q_arr, ring.mu_arr, ring.n)
     a = jnp.take(a, ring.bitrev, axis=-1)
-    return (a * ring.psi_inv_pow) % q     # post-twist * n^-1
+    return pointwise_mul(ring, a, ring.psi_inv_pow)    # post-twist * n^-1
 
 
 def negacyclic_mul(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
     """a * b in R_q via NTT."""
     return intt(ring, pointwise_mul(ring, ntt(ring, a), ntt(ring, b)))
+
+
+def coeff0_reversal(ring: Ring, b: jax.Array) -> jax.Array:
+    """r with coefficient 0 of a * b equal to sum_i a_i r_i (mod q).
+
+    Coefficient 0 of a negacyclic product is a_0 b_0 - sum_{i>=1}
+    a_i b_{n-i}, so r_0 = b_0 and r_i = -b_{n-i}.  b: [..., K, n]."""
+    flipped = jnp.concatenate([b[..., :1], jnp.flip(b[..., 1:], axis=-1)],
+                              axis=-1)
+    return jnp.concatenate([flipped[..., :1], neg(ring, flipped[..., 1:])],
+                           axis=-1)
 
 
 def naive_negacyclic_mul(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
@@ -175,32 +255,34 @@ def naive_negacyclic_mul(ring: Ring, a: jax.Array, b: jax.Array) -> jax.Array:
 # CRT decode (centered representative of a coefficient mod Q)
 # ---------------------------------------------------------------------------
 
-def _mulmod(a: jax.Array, b_int: int, m_int: int) -> jax.Array:
-    """(a * b) mod m with m up to 2^62, via double-and-add. a: any shape."""
-    acc = jnp.zeros_like(a)
-    cur = a % m_int
-    b = b_int % m_int
-    while b:
-        if b & 1:
-            acc = (acc + cur) % m_int
-        cur = (cur * 2) % m_int
-        b >>= 1
-    return acc
-
-
 def crt_centered(params: HadesParams, residues: jax.Array) -> jax.Array:
     """Reconstruct centered value in (-Q/2, Q/2] from residues [..., K].
 
-    Exact for Q < 2^62 (int64 double-and-add; the Python loop over bits is
-    unrolled at trace time, b is a static host integer).
-    """
+    Garner's mixed-radix form: v_k = (r_k - x_{<k}) * (q_0...q_{k-1})^-1
+    mod q_k, x = v_0 + q_0 (v_1 + q_1 (v_2 + ...)), exact for Q < 2^62
+    (every partial sum stays below Q)."""
+    qs = params.qs
     Q = params.Q
-    alphas = params.crt_alphas()
-    acc = jnp.zeros(residues.shape[:-1], dtype=jnp.int64)
-    for k, alpha in enumerate(alphas):
-        acc = (acc + _mulmod(residues[..., k], alpha, Q)) % Q
-    # center
-    return jnp.where(acc > Q // 2, acc - Q, acc)
+    assert Q < (1 << 62), "CRT decode needs Q < 2^62"
+    mus = barrett_mu(qs)
+    digits = [residues[..., 0]]
+    for k in range(1, len(qs)):
+        qk, muk = qs[k], int(mus[k])
+        # x_{<k} mod q_k by Horner over the digits found so far
+        acc = reduce(digits[-1], qk, muk)
+        for j in range(k - 2, -1, -1):
+            acc = addmod(mulmod(acc, qs[j] % qk, qk, muk),
+                         reduce(digits[j], qk, muk), qk)
+        prefix = 1
+        for j in range(k):
+            prefix *= qs[j]
+        inv = pow(prefix % qk, qk - 2, qk)
+        digits.append(mulmod(submod(residues[..., k], acc, qk), inv,
+                             qk, muk))
+    x = digits[-1]
+    for k in range(len(qs) - 2, -1, -1):
+        x = digits[k] + qs[k] * x
+    return jnp.where(x > Q // 2, x - Q, x)
 
 
 def to_rns(params: HadesParams, coeffs: np.ndarray) -> np.ndarray:
@@ -218,6 +300,7 @@ def const_poly(params: HadesParams, value: jax.Array) -> jax.Array:
     """
     K, n = params.num_towers, params.n
     qs = jnp.asarray(np.asarray(params.qs, dtype=np.int64))  # [K]
-    res = value[..., None] % qs                              # [..., K]
+    res = reduce_signed(value[..., None], qs,
+                        jnp.asarray(barrett_mu(params.qs)))  # [..., K]
     zeros = jnp.zeros(value.shape + (K, n), dtype=jnp.int64)
     return zeros.at[..., 0].set(res)

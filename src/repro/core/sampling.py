@@ -31,10 +31,12 @@ def uniform_poly(params: HadesParams, key: jax.Array,
 
 
 def _small_to_rns(params: HadesParams, small: jax.Array) -> jax.Array:
-    """Lift a small signed integer poly [..., n] into RNS [..., K, n]."""
+    """Lift a small signed integer poly [..., n] into RNS [..., K, n]
+    (|coefficients| < q)."""
     import numpy as np
-    qs = jnp.asarray(np.asarray(params.qs, dtype=jnp.int64))  # [K]
-    return small[..., None, :] % qs[:, None]
+    qs = jnp.asarray(np.asarray(params.qs, dtype=jnp.int64))[:, None]  # [K, 1]
+    lifted = small[..., None, :]
+    return jnp.where(lifted < 0, lifted + qs, lifted)
 
 
 def ternary_poly(params: HadesParams, key: jax.Array,

@@ -5,8 +5,7 @@ Execution model (one XLA program per stage):
   1. FILTER.  Every scan leaf of the compiled plan contributes 1 (Eq) or
      2 (Range) comparison atoms.  ALL atoms across the whole predicate
      tree are stacked into a single [A, N] batched `eval_value` call —
-     a 5-leaf plan over 34k rows is still ONE fused Eval (the same fused
-     kernel path `kernels/cmp_eval.py` lowers on TPU).  The launch
+     a 5-leaf plan over 34k rows is still ONE fused Eval.  The launch
      returns RAW eval values; each atom's decode threshold (the profile
      τ, or the predicate's ε-tolerance via `ckks.eps_to_tau`) is applied
      host-side, so mixed-ε plans share one launch and one jit cache
@@ -26,9 +25,12 @@ pair-matching strategies (tiled nested-loop grid / sort-merge) on top —
 `fused_eval`'s raw-value + host-side-threshold contract is exactly what
 lets the join grid share programs across ε's and queries.
 
-Engines: "jnp" evaluates via core/compare (reference path, CPU),
-"kernel" routes the fused stage through kernels/ops.compare (Pallas
-`cmp_eval`, compiled on TPU), "auto" picks kernel iff on TPU.
+Engines: "jnp" evaluates via core/compare, the XLA program every
+platform runs; "kernel" routes the fused stage through kernels/ops
+(Pallas `cmp_eval`).  The Pallas kernels compute on int64 vectors, which
+the TPU compiler refuses, so they run only in interpret mode on the CPU
+(tests cross-check them against the jnp path); on a TPU,
+engine="kernel" raises the compiler's error.
 """
 from __future__ import annotations
 
@@ -81,26 +83,22 @@ class QueryResult:
 
 
 def _use_kernel(engine: str) -> bool:
-    if engine == "auto":
-        return jax.default_backend() == "tpu"
     if engine in ("jnp", "kernel"):
         return engine == "kernel"
-    raise ValueError(f"unknown engine {engine!r} (jnp|kernel|auto)")
+    raise ValueError(f"unknown engine {engine!r} (jnp|kernel)")
 
 
-def _jitted(ks: KeySet, name: str, fn):
+def _jitted(ks: KeySet, name: str, fn, **jit_kw):
     """Per-KeySet jit cache (stashed on the keyset so lifetimes match).
 
-    Jitting the compare plane matters on CPU too: the fused XLA program
-    keeps the NTT pipeline in registers/cache instead of materializing
-    every eager intermediate (measured ~5-15x on scan-sized batches).
+    Jitting the compare plane matters: the fused XLA program keeps the
+    eval's intermediates in registers/cache instead of materializing
+    every eager intermediate (measured ~5-15x on scan-sized batches on
+    a CPU).
     """
-    cache = getattr(ks, "_db_jit_cache", None)
-    if cache is None:
-        cache = {}
-        object.__setattr__(ks, "_db_jit_cache", cache)
+    cache = ks.cache("db_jit")
     if name not in cache:
-        cache[name] = jax.jit(fn)
+        cache[name] = jax.jit(fn, **jit_kw)
     return cache[name]
 
 
@@ -122,10 +120,10 @@ def jitted_dedup_eval(ks: KeySet, axis: int = 0):
     the tile size, never as a materialized A·N stack.
 
     In paper mode the dedup goes further: `eval_value` is LINEAR in the
-    ciphertext pair (ctΔ then `scale·c0 + cek⊛c1` — NTT, pointwise, and
-    scalar ops are all exact mod-q linear maps), so the expensive
-    column-side transform runs ONCE per unique column and the per-atom
-    work collapses to a gather + coefficient-0 subtract.  A same-column
+    ciphertext pair (ctΔ then coefficient 0 of `scale·c0 + cek⊛c1` — an
+    exact mod-q linear map), so the column-side inner product runs ONCE
+    per unique column and the per-atom work collapses to a gather +
+    coefficient-0 subtract.  A same-column
     batch of A atoms costs ~1 column transform instead of A — bit-
     identical raw values, this is pure factoring.  Gadget mode keeps
     the joint form: `gadget_keymul` digit-decomposes its operand, which
@@ -134,15 +132,14 @@ def jitted_dedup_eval(ks: KeySet, axis: int = 0):
 
     def g0(ct0, ct1):
         # coefficient-0 eval part of one ciphertext: [..., K]
-        rng = ks.ring
-        scaled = R.scalar_mul(rng, ct0, ks.params.scale)
-        keyed = R.negacyclic_mul(rng, ct1, ks.cek)
-        return R.add(rng, scaled, keyed)[..., :, 0]
+        q, mu = ks.ring.q_arr[:, 0], ks.ring.mu_arr[:, 0]
+        scaled = R.mulmod(ct0[..., :, 0], ks.params.scale, q, mu)
+        return R.addmod(scaled, C.keyed_coeff0(ks, ct1), q)
 
     if ks.params.mode == "paper":
         def fn(uc0, uc1, sel, b0, b1):
             g_col = jnp.take(g0(uc0, uc1), sel, axis=axis)
-            diff = (g_col - g0(b0, b1)) % ks.ring.q_arr[:, 0]
+            diff = R.submod(g_col, g0(b0, b1), ks.ring.q_arr[:, 0])
             return R.crt_centered(ks.params, diff)
     else:
         def fn(uc0, uc1, sel, b0, b1):
@@ -150,6 +147,15 @@ def jitted_dedup_eval(ks: KeySet, axis: int = 0):
                              jnp.take(uc1, sel, axis=axis))
             return C.eval_value(ks, col, Ciphertext(b0, b1))
     return _jitted(ks, f"dedup_eval_ax{axis}", fn)
+
+
+def scan_tile(x: jax.Array, lo: int, t: int, axis: int = 0) -> jax.Array:
+    """Rows [lo, lo + t) of a column block along its row `axis`: the one
+    way both executors cut a scan tile.  An eager slice (one compiled
+    slice per shape and t; the offset is an operand), so the eval
+    program takes only the tile.  On a TPU the slice program holds one
+    32-bit plane of the int64 block while it splits it into words."""
+    return jax.lax.dynamic_slice_in_dim(x, lo, t, axis=axis)
 
 
 def dedup_atom_columns(table, atoms: List[P.Atom],
@@ -186,9 +192,14 @@ def atom_tau(ks: KeySet, atom: P.Atom) -> int:
 
 
 def jitted_comparator(ks: KeySet):
-    """Jitted Alg. 4 trapdoor comparator in `encrypted_sort` signature."""
+    """Jitted Alg. 4 trapdoor comparator in `encrypted_sort` signature
+    (one object per KeySet, so the sort networks' jitted stages, cached
+    per comparator, are shared too)."""
     fae = _jitted(ks, "cmp_fae", lambda a, b: C.compare_fae(ks, a, b))
-    return lambda _ks, a, b: fae(a, b)
+    cache = ks.cache("db_jit")
+    if "cmp_fae_sig" not in cache:
+        cache["cmp_fae_sig"] = lambda _ks, a, b: fae(a, b)
+    return cache["cmp_fae_sig"]
 
 
 def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
@@ -198,17 +209,19 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
     (N = `table.scan_width`: a pending delta run's slots ride the SAME
     program as the base block — base ∪ delta costs one pass, not two).
 
-    Duplicate-free and working-set bounded: the host stacks each
-    DISTINCT column ONCE ([U, N] bytes moved, not [A, N] — K range
-    queries over one column used to ship K full copies) and the
-    per-atom gather + [A, 1] bounds broadcast happen INSIDE the jitted
-    program.  Rows tile into power-of-two chunks of T with A·T lanes
-    within the lane budget (`kernels.ops.lane_tile`; explicit
-    `lane_budget` > `set_lane_budget` > `REPRO_LANE_BUDGET` > default),
-    so peak intermediates stay off the bandwidth cliff however many
-    atoms a batch fuses — each tile is one launch, same shapes across
-    queries, at most one extra ragged-tail shape when N is not a
-    multiple of T.
+    Duplicate-free and working-set bounded: each DISTINCT column is read
+    ONCE ([U, N] bytes moved, not [A, N] — K range queries over one
+    column used to ship K full copies): each tile is cut from the
+    column blocks (`scan_tile`) and stacked over the U columns, and the
+    per-atom gather and the [A, 1] bounds broadcast happen INSIDE the
+    jitted program, so no launch copies a column.  The
+    base block and a pending delta block tile separately, each into
+    power-of-two chunks of T rows with A·T lanes within the lane budget
+    (`kernels.ops.lane_tile`; explicit `lane_budget` > `set_lane_budget`
+    > `REPRO_LANE_BUDGET` > the device-derived default), so peak
+    intermediates stay bounded however many atoms a batch fuses — each
+    tile is one launch, same shapes across queries, at most one extra
+    ragged-tail shape per block.
 
     Thresholds are deliberately NOT applied here: each atom decodes its
     own τ (profile default or ε-derived) host-side in `scan_leaf_mask`,
@@ -218,33 +231,47 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
     with obs.span("executor.fused_eval", atoms=len(atoms),
                   rows=table.scan_width):
         A, W = len(atoms), table.scan_width
-        uniq, sel = dedup_atom_columns(table, atoms, table.scan_column)
+        order: Dict[str, int] = {}
+        for a in atoms:
+            order.setdefault(a.column, len(order))
+        sel = np.asarray([order[a.column] for a in atoms], np.int64)
+        parts = [table.scan_parts(c) for c in order]   # [U][block]
         bounds = stack_atom_bounds(atoms)
-        T = KO.lane_tile(W, A, lane_budget)
-        # host<->device traffic is the deduped reality: U unique column
-        # stacks + A bounds, counted once however many tiles launch
-        obs.count("bytes.moved", 2 * (uniq.c0.nbytes + bounds.c0.nbytes))
+        # host<->device traffic is the deduped reality: U unique columns
+        # + A bounds, counted once however many tiles launch
+        row_bytes = bounds.c0.nbytes // A
+        obs.count("bytes.moved",
+                  2 * (len(order) * W * row_bytes + bounds.c0.nbytes))
         use_kernel = _use_kernel(engine)
+        default = KO.device_lane_budget(ks.params)
         sel_j = jnp.asarray(sel)
         out = np.empty((A, W), dtype=np.int64)
-        for lo in range(0, W, T):
-            t = min(T, W - lo)
-            with obs.span("executor.eval_tile", offset=lo, rows=t) as tsp:
-                tile = Ciphertext(uniq.c0[:, lo:lo + t],
-                                  uniq.c1[:, lo:lo + t])
-                obs.jit_launch("executor.fused_eval", tile.c0, bounds.c0)
-                obs.count("eval.launches")
-                obs.count("eval.tiles")
-                obs.count("eval.lanes", A * t)
-                if use_kernel:
-                    col = Ciphertext(jnp.take(tile.c0, sel_j, axis=0),
-                                     jnp.take(tile.c1, sel_j, axis=0))
-                    vals = tsp.sync(KO.broadcast_eval_values(ks, col,
-                                                             bounds))
-                else:
-                    vals = tsp.sync(jitted_dedup_eval(ks)(
-                        tile.c0, tile.c1, sel_j, bounds.c0, bounds.c1))
-                out[:, lo:lo + t] = np.asarray(vals)
+        off = 0
+        for blk in zip(*parts):
+            Wb = blk[0].c0.shape[0]
+            T = KO.lane_tile(Wb, A, lane_budget, default=default)
+            for lo in range(0, Wb, T):
+                t = min(T, Wb - lo)
+                with obs.span("executor.eval_tile", offset=off + lo,
+                              rows=t) as tsp:
+                    obs.jit_launch("executor.fused_eval",
+                                   (len(blk), t) + blk[0].c0.shape[1:],
+                                   bounds.c0)
+                    obs.count("eval.launches")
+                    obs.count("eval.tiles")
+                    obs.count("eval.lanes", A * t)
+                    c0 = jnp.stack([scan_tile(c.c0, lo, t) for c in blk])
+                    c1 = jnp.stack([scan_tile(c.c1, lo, t) for c in blk])
+                    if use_kernel:
+                        col = Ciphertext(jnp.take(c0, sel_j, axis=0),
+                                         jnp.take(c1, sel_j, axis=0))
+                        vals = tsp.sync(KO.broadcast_eval_values(ks, col,
+                                                                 bounds))
+                    else:
+                        vals = tsp.sync(jitted_dedup_eval(ks)(
+                            c0, c1, sel_j, bounds.c0, bounds.c1))
+                    out[:, off + lo:off + lo + t] = np.asarray(vals)
+            off += Wb
         return out
 
 

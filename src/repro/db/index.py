@@ -76,9 +76,10 @@ class SortedIndex:
         trapdoor compares); amortized over every subsequent lookup."""
         col = table.gather(column, np.arange(table.n_rows))
         if comparator is None:
-            # jit once: every network stage reuses the same [pairs] shape
-            jitted = jax.jit(lambda a, b: C.compare_fae(ks, a, b))
-            comparator = lambda _ks, a, b: jitted(a, b)  # noqa: E731
+            # the per-KeySet jitted comparator: every network stage, and
+            # every later build of the same size, reuses one program
+            from repro.db.executor import jitted_comparator
+            comparator = jitted_comparator(ks)
         sorted_ct, perm = C.encrypted_sort(ks, col, comparator)
         return cls(column, sorted_ct, np.asarray(perm),
                    build_compares=C.bitonic_compare_count(table.n_rows))
@@ -93,11 +94,13 @@ class SortedIndex:
     # -- search ------------------------------------------------------------
 
     def _eval(self, ks: KeySet) -> Callable:
-        """Jitted raw probe Eval (jit specializes per lane shape).  The
-        three-way decode happens host-side so each lane applies its own
-        τ (profile default or ε-derived)."""
+        """Jitted raw probe Eval (jit specializes per lane shape), shared
+        per KeySet so a fresh delta-run index reuses compiled probes.
+        The three-way decode happens host-side so each lane applies its
+        own τ (profile default or ε-derived)."""
         if self._cmp is None:
-            self._cmp = jax.jit(lambda a, b: C.eval_value(ks, a, b))
+            from repro.db.executor import jitted_eval
+            self._cmp = jitted_eval(ks)
         return self._cmp
 
     def _lane_taus(self, ks: KeySet, n_lanes: int,

@@ -70,11 +70,14 @@ from repro.db.table import Table, rows_to_mask
 DEFAULT_BLOCK_PAIRS = 1 << 14
 
 
-def _resolve_block_pairs(block_pairs: Optional[int]) -> int:
+def _resolve_block_pairs(ks: KeySet, block_pairs: Optional[int]) -> int:
     """The effective pair budget for a grid launch: explicit argument >
-    shared lane-budget overrides > `DEFAULT_BLOCK_PAIRS`."""
+    shared lane-budget overrides > `DEFAULT_BLOCK_PAIRS`, cut to what
+    the device holds (`kernels.ops.device_lane_budget`)."""
     from repro.kernels import ops as KO
-    return KO.resolve_lane_budget(block_pairs, default=DEFAULT_BLOCK_PAIRS)
+    return KO.resolve_lane_budget(
+        block_pairs, default=min(DEFAULT_BLOCK_PAIRS,
+                                 KO.device_lane_budget(ks.params)))
 
 
 @dataclasses.dataclass
@@ -177,7 +180,7 @@ def pair_eval_values(ks: KeySet, left_ct: Ciphertext, right_ct: Ciphertext,
     share these launches (the `fused_eval` contract, extended to row
     pairs).
     """
-    block_pairs = _resolve_block_pairs(block_pairs)
+    block_pairs = _resolve_block_pairs(ks, block_pairs)
     L = int(left_ct.c0.shape[0])
     R = int(right_ct.c0.shape[0])
     T = _grid_tile(block_pairs, L, R)

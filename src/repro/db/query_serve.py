@@ -38,7 +38,7 @@ delta outgrows the threshold.
 
 Usage:
   PYTHONPATH=src python -m repro.db.query_serve --dataset hg38 \
-      --requests 8 --batch 4 --rows 4096
+      --requests 8 --batch 4 --rows 4096 [--profile paper-bfv --mode gadget]
 """
 from __future__ import annotations
 
@@ -525,6 +525,19 @@ class QueryServer:
 # CLI demo: random range queries against a paper dataset
 # ---------------------------------------------------------------------------
 
+def dataset_column(params, dataset: str, rows: Optional[int] = None
+                   ) -> np.ndarray:
+    """A paper dataset as the integer column the demo tables encrypt:
+    the first `rows` rows (None or 0 = all), reduced into the compare
+    path's headroom (|value| <= max_operand / 2, which the sort
+    networks' sentinels need)."""
+    from repro.data import load_dataset
+    vals = load_dataset(dataset, scheme="bfv", t=params.t)
+    if rows:
+        vals = vals[:rows]
+    return (vals % (params.max_operand // 2)).astype(np.int64)
+
+
 def main(argv=None) -> dict:
     """CLI demo: serve random encrypted range queries over a paper
     dataset in batches (see the module docstring for usage)."""
@@ -532,10 +545,15 @@ def main(argv=None) -> dict:
 
     from repro.core import encrypt as E
     from repro.core.keys import keygen
-    from repro.core.params import make_params
-    from repro.data import load_dataset
+    from repro.core.params import PROFILES, make_params
 
     ap = argparse.ArgumentParser()
+    ap.add_argument("--profile", default="test-bfv",
+                    choices=[n for n, p in PROFILES.items()
+                             if p.scheme == "bfv"],
+                    help="ring profile (paper-bfv = the paper's widths)")
+    ap.add_argument("--mode", default="gadget", choices=["gadget", "paper"],
+                    help="CEK mode (gadget = full-noise key)")
     ap.add_argument("--dataset", default="hg38")
     ap.add_argument("--rows", type=int, default=4096,
                     help="0 = full dataset")
@@ -549,12 +567,9 @@ def main(argv=None) -> dict:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
 
-    params = make_params("test-bfv", mode="gadget")
+    params = make_params(args.profile, mode=args.mode)
     ks = keygen(params, jax.random.PRNGKey(args.seed))
-    vals = load_dataset(args.dataset, scheme="bfv", t=params.t)
-    if args.rows:
-        vals = vals[:args.rows]
-    vals = (vals % (params.max_operand // 2)).astype(np.int64)
+    vals = dataset_column(params, args.dataset, args.rows)
 
     table = Table.from_arrays(ks, args.dataset, {"value": vals},
                               jax.random.PRNGKey(args.seed + 1))
@@ -583,6 +598,7 @@ def main(argv=None) -> dict:
     wall = time.perf_counter() - t0
     correct = sum(int(len(r) == truth[qid]) for qid, r in results.items())
     out = {
+        "profile": args.profile, "mode": args.mode,
         "dataset": args.dataset, "rows": int(len(vals)),
         "requests": args.requests, "batch": args.batch,
         "indexed": bool(args.index),

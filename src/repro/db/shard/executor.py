@@ -74,7 +74,8 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
         W = stable.shard_scan_width
         uniq, sel = X.dedup_atom_columns(stable, atoms, stable.scan_stack)
         bounds = X.stack_atom_bounds(atoms)
-        T = KO.lane_tile(W, S * A, lane_budget)
+        T = KO.lane_tile(W, S * A, lane_budget,
+                         default=KO.device_lane_budget(ks.params))
         obs.count("bytes.moved", 2 * (uniq.c0.nbytes + bounds.c0.nbytes))
         use_kernel = X._use_kernel(engine)
         spec = stable.spec
@@ -85,8 +86,8 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
         for lo in range(0, W, T):
             t = min(T, W - lo)
             with obs.span("shard.eval_tile", offset=lo, rows=t) as tsp:
-                tile = Ciphertext(uniq.c0[:, :, lo:lo + t],
-                                  uniq.c1[:, :, lo:lo + t])
+                tile = Ciphertext(X.scan_tile(uniq.c0, lo, t, axis=2),
+                                  X.scan_tile(uniq.c1, lo, t, axis=2))
                 obs.jit_launch("shard.fused_eval", tile.c0, bounds.c0)
                 obs.count("eval.launches")
                 obs.count("eval.tiles")

@@ -1,9 +1,9 @@
 """`ShardedIndex`: one HADES sorted index per shard, probed fan-out.
 
-Build is batched across shards: every shard's valid rows pad to one
-common block and ONE tiled bitonic network sorts all shards together
-(each stage a single batched Eval — `merge.shard_block_sort`), then the
-per-shard `SortedIndex` objects are carved out by id-stripping.
+Build: every shard's valid rows pad to one common block and a bitonic
+network sorts each block on the device that holds the shard (each stage
+a single batched Eval — `merge.shard_block_sort`); the per-shard
+`SortedIndex` objects are carved out by id-stripping.
 
 Lookups broadcast the client's encrypted trapdoor to every shard and
 binary-search ALL shards' indexes in one lane-batched launch: a probe
@@ -33,7 +33,8 @@ from repro.db.table import rows_to_mask
 
 
 class ShardedIndex:
-    """Per-shard SortedIndexes + stacked sorted rows for fan-out probes."""
+    """Per-shard SortedIndexes, each on its shard's device, probed
+    together (fan-out search)."""
 
     def __init__(self, column: str, shards: List[SortedIndex], *,
                  build_compares: int = 0):
@@ -46,19 +47,6 @@ class ShardedIndex:
         # `search` call — the per-query attribution the batched servers
         # bill from (the scalar above is only the cumulative total)
         self.last_probe_counts = np.zeros(0, np.int64)
-        n_max = int(self.counts.max())
-        c0s, c1s = [], []
-        for ix in shards:
-            c0, c1 = ix.sorted_ct.c0, ix.sorted_ct.c1
-            pad = n_max - c0.shape[0]
-            if pad:   # never probed (hi is clamped to the shard's count)
-                c0 = jnp.concatenate([c0, jnp.zeros((pad,) + c0.shape[1:],
-                                                    c0.dtype)])
-                c1 = jnp.concatenate([c1, jnp.zeros((pad,) + c1.shape[1:],
-                                                    c1.dtype)])
-            c0s.append(c0)
-            c1s.append(c1)
-        self._sorted = Ciphertext(jnp.stack(c0s), jnp.stack(c1s))  # [S,Nm,..]
         self._cmp: Optional[Callable] = None
 
     # -- construction ------------------------------------------------------
@@ -66,33 +54,50 @@ class ShardedIndex:
     @classmethod
     def build(cls, ks: KeySet, stable: ShardedTable,
               column: str) -> "ShardedIndex":
-        """Sort every shard's column in ONE batched per-shard network."""
-        S = stable.num_shards
-        block = C.next_pow2(int(stable.shard_rows.max()))
-        per_shard = []
-        for s in range(S):
-            m = int(stable.shard_rows[s])
-            per_shard.append((stable.gather(column, s, np.arange(m)),
-                              np.arange(m, dtype=np.int64)))
-        ct, ids = M.pad_shard_blocks(ks, per_shard, block=block,
-                                     pad_value=ks.params.max_operand // 2,
-                                     num_blocks=S)
+        """Sort every shard's column, each padded to the common block,
+        on the device that holds the shard: no device ever holds more
+        than its own shard's network.  (Real rows land exactly where
+        one batched network over all shards would put them: the pads
+        are sentinels above every value, so no decision involving a
+        real row depends on which pad it meets.)  The pads encrypt on
+        the default device, one shard at a time, each block complete on
+        its shard's device before the next shard's pads start; then
+        every shard's network is dispatched before any result is read
+        back, so on a mesh the shards sort concurrently, each on its
+        own device."""
         from repro.db.executor import jitted_comparator
-        c0, c1, gid, compares = M.shard_block_sort(
-            ks, jitted_comparator(ks), ct.c0, ct.c1, jnp.asarray(ids),
-            block=block)
-        gid = np.asarray(gid)
+        cmp = jitted_comparator(ks)
+        block = C.next_pow2(int(stable.shard_rows.max()))
+        blocks = []
+        for s in range(stable.num_shards):
+            m = int(stable.shard_rows[s])
+            base = stable.shard_block(column, s)
+            rows = Ciphertext(base.c0[:m], base.c1[:m])
+            del base                     # one shard-sized copy at a time
+            ct, ids = M.pad_shard_blocks(
+                ks, [(rows, np.arange(m, dtype=np.int64))], block=block,
+                pad_value=ks.params.max_operand // 2, num_blocks=1,
+                pad_salt=s)
+            del rows
+            blocks.append((jax.block_until_ready(ct), jnp.asarray(ids)))
+            del ct
+        networks = []
+        while blocks:                    # each block freed as it sorts
+            ct, ids = blocks.pop(0)
+            networks.append(M.shard_block_sort(
+                ks, cmp, ct.c0, ct.c1, ids, block=block))
+            del ct
         shards = []
-        for s in range(S):
-            sl = slice(s * block, (s + 1) * block)
-            keep = np.nonzero(gid[sl] >= 0)[0] + s * block
+        for c0, c1, gid, n in networks:
+            gid = np.asarray(gid)
+            keep = np.nonzero(gid >= 0)[0]
+            # each shard rode a block-row network (the common padded
+            # block, not its own row count)
             shards.append(SortedIndex(
                 column, Ciphertext(c0[keep], c1[keep]), gid[keep],
-                # each shard rode a block-row network (the common padded
-                # block, not its own row count) — attribute that share so
-                # per-shard counts sum to the batched total
-                build_compares=C.bitonic_compare_count(block)))
-        return cls(column, shards, build_compares=compares)
+                build_compares=n))
+        return cls(column, shards,
+                   build_compares=sum(ix.build_compares for ix in shards))
 
     # -- fan-out search ----------------------------------------------------
 
@@ -103,7 +108,8 @@ class ShardedIndex:
 
     def _eval(self, ks: KeySet) -> Callable:
         if self._cmp is None:
-            self._cmp = jax.jit(lambda a, b: C.eval_value(ks, a, b))
+            from repro.db.executor import jitted_eval
+            self._cmp = jitted_eval(ks)
         return self._cmp
 
     def search(self, ks: KeySet, values: Ciphertext, strict: np.ndarray,
@@ -127,7 +133,21 @@ class ShardedIndex:
         ev = self._eval(ks)
         lo = np.zeros((S, B), np.int64)
         hi = np.broadcast_to(self.counts[:, None], (S, B)).copy()
-        s_idx = np.arange(S)[:, None]
+        home = values.c0.devices().pop()
+
+        def gather(probe):
+            # each shard's probed rows, gathered where the shard lives,
+            # then brought beside the trapdoors: [S, B, ...]
+            out0, out1 = [], []
+            for s, ix in enumerate(self.shards):
+                c0, c1 = ix.sorted_ct
+                if ix.n_rows:
+                    c0, c1 = c0[probe[s]], c1[probe[s]]
+                else:          # an empty shard's lanes are all dead
+                    c0 = c1 = jnp.zeros((B,) + c0.shape[1:], c0.dtype)
+                out0.append(jax.device_put(c0, home))
+                out1.append(jax.device_put(c1, home))
+            return Ciphertext(jnp.stack(out0), jnp.stack(out1))
         lane_probes = np.zeros(B, np.int64)
         with obs.span("shard.index.search", column=self.column,
                       shards=S, lanes=B) as sp:
@@ -135,8 +155,7 @@ class ShardedIndex:
                 active = lo < hi
                 mid = (lo + hi) // 2
                 probe = np.where(active, mid, 0)
-                rows = Ciphertext(self._sorted.c0[s_idx, probe],
-                                  self._sorted.c1[s_idx, probe])  # [S,B,...]
+                rows = gather(probe)
                 obs.jit_launch("shard.index.probe", rows.c0, values.c0)
                 obs.count("eval.launches")
                 obs.count("eval.lanes", S * B)

@@ -73,7 +73,7 @@ def sharded_pair_eval(ks: KeySet, left: ShardedTable, right: ShardedTable,
     Meshless, the grid flattens to a [S_l·N_l, S_r·N_r] pair matrix and
     reuses the tiled single-table launches.  Either way, thresholds are
     NOT applied here (the `fused_eval` raw-value contract)."""
-    block_pairs = J._resolve_block_pairs(block_pairs)
+    block_pairs = J._resolve_block_pairs(ks, block_pairs)
     lct, rct = left.columns[lcol], right.columns[rcol]
     S_l, N_l = lct.c0.shape[:2]
     S_r, N_r = rct.c0.shape[:2]

@@ -162,8 +162,8 @@ def topk_tournament(ks: KeySet, cmp: Callable, c0, c1, ids, *, kp: int,
 # ---------------------------------------------------------------------------
 
 def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
-                     pad_value: int, num_blocks: int) -> Tuple[Ciphertext,
-                                                               np.ndarray]:
+                     pad_value: int, num_blocks: int,
+                     pad_salt: int = 0) -> Tuple[Ciphertext, np.ndarray]:
     """Stack per-shard (Ciphertext, global-id array) candidate lists into
     one flattened `[num_blocks·block]` column.
 
@@ -172,8 +172,11 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
     padding); missing shards (num_blocks = next_pow2(S) > S) become
     all-sentinel blocks.  Pad slots carry id -1 — stripping is by id,
     never by value, exactly the core networks' tie-robust contract.
+    Block s's pads encrypt under key `pad_salt + s`, so a caller padding
+    shard s alone (`num_blocks=1, pad_salt=s`) gets the pads it would
+    get in the stack.
     """
-    from repro.core import encrypt as E
+    from repro.db.table import encrypt_constant
     pad_key = jax.random.PRNGKey(0x5A4D)
     c0s, c1s, ids = [], [], []
     for s in range(num_blocks):
@@ -184,8 +187,8 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
         parts0 = [ct.c0] if m else []
         parts1 = [ct.c1] if m else []
         if m < block:
-            pad = E.encrypt(ks, jnp.full((block - m,), pad_value, jnp.int64),
-                            jax.random.fold_in(pad_key, s))
+            pad = encrypt_constant(ks, pad_value, block - m,
+                                   jax.random.fold_in(pad_key, pad_salt + s))
             parts0.append(pad.c0)
             parts1.append(pad.c1)
         c0s.append(jnp.concatenate(parts0) if len(parts0) > 1 else parts0[0])

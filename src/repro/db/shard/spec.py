@@ -64,6 +64,35 @@ class ShardSpec:
         placement; a 1-device mesh gains nothing over plain jit)."""
         return self.placeable and self.mesh_devices > 1
 
+    def shard_devices(self) -> Optional[list]:
+        """The device holding each shard when stacks split over the mesh
+        (None when everything stays on the default device)."""
+        if not self.placeable or self.mesh_devices == 1:
+            return None
+        per = self.num_shards // self.mesh_devices
+        devs = list(self.mesh.devices.flat)
+        return [devs[s // per] for s in range(self.num_shards)]
+
+    def stack(self, parts: list):
+        """[S, ...] array from per-shard arrays, each already on its
+        shard's device (`shard_devices`): every device stacks its own
+        shards and the global array is assembled in place, so no device
+        ever holds more than its share.  Meshless: a plain stack."""
+        devs = self.shard_devices()
+        if devs is None:
+            import jax.numpy as jnp
+            return jnp.stack(parts)
+        import jax
+        import jax.numpy as jnp
+
+        from repro.parallel.sharding import leading_sharding
+        per = self.num_shards // self.mesh_devices
+        local = [jnp.stack(parts[i:i + per])
+                 for i in range(0, self.num_shards, per)]
+        shape = (self.num_shards,) + tuple(parts[0].shape)
+        return jax.make_array_from_single_device_arrays(
+            shape, leading_sharding(self.mesh, len(shape), self.axis), local)
+
     def place(self, tree):
         """Pin every [S, ...] array leaf's leading dim to the mesh.  A
         no-op when the spec has no usable mesh, so callers never branch."""

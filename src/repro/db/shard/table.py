@@ -40,7 +40,8 @@ from repro.core.compare import next_pow2
 from repro.core.encrypt import Ciphertext
 from repro.core.keys import KeySet
 from repro.db.shard.spec import ShardSpec
-from repro.db.table import Table, append_rows, concat_ct_rows
+from repro.db.table import (Table, append_rows, concat_ct_rows,
+                            encrypt_constant)
 
 # compaction-fold pad rows (encryptions of 0) derive keys from this seed
 _FOLD_PAD_SEED = 0xC0FD
@@ -116,11 +117,17 @@ class ShardedTable:
 
         Each shard's chunk encrypts under its own fold_in key via
         `Table.from_arrays` (one batched encrypt per column per shard),
-        all padded to the common N_sp block.
+        all padded to the common N_sp block.  On a mesh, each shard
+        encrypts on the default device (one compiled program serves
+        every shard) and has moved to the device that holds it before
+        the next shard starts (`ShardSpec.stack` then assembles in
+        place), so no device holds more than its own shards and the one
+        in flight.
         """
         n_rows = len(next(iter(data.values())))
         offsets = partition_offsets(n_rows, spec.num_shards)
         n_sp = next_pow2(int(np.diff(offsets).max()))
+        devs = spec.shard_devices()
         stacks: Dict[str, list] = {c: [] for c in data}
         for s in range(spec.num_shards):
             lo, hi = int(offsets[s]), int(offsets[s + 1])
@@ -128,11 +135,14 @@ class ShardedTable:
             t = Table.from_arrays(ks, f"{name}.s{s}", chunk,
                                   jax.random.fold_in(key, s), n_padded=n_sp)
             for c in data:
-                stacks[c].append(t.columns[c])
-        columns = {c: Ciphertext(jnp.stack([ct.c0 for ct in cts]),
-                                 jnp.stack([ct.c1 for ct in cts]))
+                stacks[c].append(t.columns[c] if devs is None else
+                                 jax.block_until_ready(jax.device_put(
+                                     t.columns[c], devs[s])))
+            del t          # shard s has left the default device
+        columns = {c: Ciphertext(spec.stack([ct.c0 for ct in cts]),
+                                 spec.stack([ct.c1 for ct in cts]))
                    for c, cts in stacks.items()}
-        return cls(name, spec.place(columns), offsets, spec)
+        return cls(name, columns, offsets, spec)
 
     @classmethod
     def from_table(cls, ks: KeySet, table: Table, *,
@@ -160,8 +170,8 @@ class ShardedTable:
                     # same pad semantics as `Table` ingest (pad_rows_pow2
                     # with pad_value=0): genuine encryptions of 0, masked
                     # out by shard validity
-                    pad = E.encrypt(
-                        ks, jnp.zeros(n_sp - (hi - lo), jnp.int64),
+                    pad = encrypt_constant(
+                        ks, 0, n_sp - (hi - lo),
                         jax.random.fold_in(pad_key, ci * 1024 + s))
                     c0 = jnp.concatenate([c0, pad.c0])
                     c1 = jnp.concatenate([c1, pad.c1])
@@ -362,8 +372,8 @@ class ShardedTable:
                     parts.append(Ciphertext(dct.c0[:ds], dct.c1[:ds]))
                 if b + ds < new_sp:
                     salt = ci * 65536 + s * 256 + self.version % 256
-                    parts.append(E.encrypt(
-                        ks, jnp.zeros(new_sp - b - ds, jnp.int64),
+                    parts.append(encrypt_constant(
+                        ks, 0, new_sp - b - ds,
                         jax.random.fold_in(pad_key, salt)))
                 stacked = concat_ct_rows(*parts)
                 c0s.append(stacked.c0)
@@ -435,11 +445,54 @@ class ShardedTable:
                 for c, ct in self.columns.items()}
         return Table(f"{self.name}.s{s}", cols, int(self.shard_rows[s]))
 
+    @staticmethod
+    def _piece(x: jax.Array, s: int):
+        """(the device-local array holding shard s of stack x, s's index
+        in it)."""
+        for piece in x.addressable_shards:
+            start, stop, _ = piece.index[0].indices(x.shape[0])
+            if start <= s < stop:
+                return piece.data, s - start
+        raise ValueError(f"shard {s} is not addressable here")
+
+    def shard_block(self, name: str, s: int) -> Ciphertext:
+        """Shard s's BASE block [N_sp, K, n], read where it lives: on a
+        mesh, from the device holding shard s (no cross-device
+        program touches the rest of the stack)."""
+        ct = self.columns[name]
+        if self.spec.shard_devices() is None:
+            return Ciphertext(ct.c0[s], ct.c1[s])
+        (d0, i), (d1, _) = self._piece(ct.c0, s), self._piece(ct.c1, s)
+        return Ciphertext(d0[i], d1[i])
+
+    def _base_rows(self, name: str, s, pos) -> Ciphertext:
+        """Rows pos[j] of shard s[j]'s BASE block.  On a mesh, each
+        shard's rows are gathered on the device holding it and brought
+        to the default device — a gather over the sharded stack could
+        have the compiler assemble the whole stack on one device."""
+        ct = self.columns[name]
+        if self.spec.shard_devices() is None:
+            return Ciphertext(ct.c0[s, pos], ct.c1[s, pos])
+        s, pos = np.asarray(s, np.int64), np.asarray(pos, np.int64)
+        home = jax.devices()[0]
+        order, parts0, parts1 = [], [], []
+        for sh in np.unique(s):
+            sel = np.nonzero(s == sh)[0]
+            order.append(sel)
+            for parts, x in ((parts0, ct.c0), (parts1, ct.c1)):
+                data, i = self._piece(x, int(sh))
+                parts.append(jax.device_put(data[i, pos[sel]], home))
+        if not order:
+            empty = jnp.zeros((0,) + ct.c0.shape[2:], ct.c0.dtype)
+            return Ciphertext(empty, empty)
+        inv = np.argsort(np.concatenate(order))
+        return Ciphertext(jnp.concatenate(parts0)[inv],
+                          jnp.concatenate(parts1)[inv])
+
     def gather(self, name: str, s: int, local_rows) -> Ciphertext:
         """Ciphertext rows of shard s's BASE block at local slots."""
         idx = np.asarray(local_rows, np.int64)
-        ct = self.columns[name]
-        return Ciphertext(ct.c0[s, idx], ct.c1[s, idx])
+        return self._base_rows(name, np.full(idx.shape, s, np.int64), idx)
 
     def scan_stack(self, name: str) -> Ciphertext:
         """The named column over the UNION scan: `[S, shard_scan_width,
@@ -478,13 +531,14 @@ class ShardedTable:
         s, pos = self._gid_shard[gids], self._gid_pos[gids]
         in_delta = self._gid_in_delta[gids]
         if not in_delta.any():
-            return Ciphertext(ct.c0[s, pos], ct.c1[s, pos])
+            return self._base_rows(name, s, pos)
         c0 = jnp.zeros((gids.size,) + ct.c0.shape[2:], ct.c0.dtype)
         c1 = jnp.zeros((gids.size,) + ct.c1.shape[2:], ct.c1.dtype)
         bi = np.nonzero(~in_delta)[0]
         if bi.size:
-            c0 = c0.at[bi].set(ct.c0[s[bi], pos[bi]])
-            c1 = c1.at[bi].set(ct.c1[s[bi], pos[bi]])
+            base = self._base_rows(name, s[bi], pos[bi])
+            c0 = c0.at[bi].set(base.c0)
+            c1 = c1.at[bi].set(base.c1)
         for sh in np.unique(s[in_delta]):
             di = np.nonzero(in_delta & (s == sh))[0]
             dct = self.deltas[int(sh)].columns[name]

@@ -8,8 +8,10 @@ results.  The pad rows are real encryptions of 0 — the server cannot
 distinguish them from data rows by inspection, only the table's public
 row count reveals the split.
 
-Encryption is batched: one `encrypt` call per column, regardless of row
-count (the vectorized LPR path in core/encrypt.py).
+Encryption is batched and chunked: `encrypt_rows` encrypts a column
+`INGEST_CHUNK_ROWS` rows per launch straight into its preallocated
+device buffers, so ingest peaks at the column plus one chunk of
+intermediates (a paper-bfv hg38 column alone is 8 GiB).
 
 WRITE PATH.  A table is mutable through `insert` / `update` / `delete`:
 
@@ -49,6 +51,10 @@ from repro.core.keys import KeySet
 # compaction) encrypt 0 under keys folded from this seed — same
 # public-key construction as `ShardedTable.from_table`'s 0x5AAD pads
 _APPEND_PAD_SEED = 0xDE17A
+
+# rows per encrypt launch at ingest: one chunk's NTT intermediates sit
+# beside the column being filled
+INGEST_CHUNK_ROWS = 4096
 
 
 def rows_to_mask(rows, n_padded: int) -> np.ndarray:
@@ -103,12 +109,60 @@ def concat_ct_rows(*cts: Ciphertext) -> Ciphertext:
                       jnp.concatenate([ct.c1 for ct in cts]))
 
 
+def encrypt_rows(ks: KeySet, m: jax.Array, key: jax.Array, *,
+                 fae: bool = False) -> Ciphertext:
+    """Encrypt a [N] plaintext column on the device,
+    `INGEST_CHUNK_ROWS` rows per launch.  A column of at most one chunk
+    is one `encrypt` call under `key`; a longer one (N a multiple of the
+    chunk) is written chunk by chunk into preallocated [N, K, n] buffers
+    (donated, so updated in place), chunk i encrypting under
+    `fold_in(key, i)`.  The encrypt program depends on the chunk size
+    only, not on N."""
+    chunk = INGEST_CHUNK_ROWS
+    N = int(m.shape[0])
+    if N <= chunk:
+        return (E.encrypt_fae if fae else E.encrypt)(ks, m, key)
+    if N % chunk:
+        raise ValueError(f"{N} rows do not split into chunks of {chunk}")
+    from repro.db.executor import _jitted     # circular at module scope
+
+    def enc(m, key, i):
+        return (E.encrypt_fae if fae else E.encrypt)(
+            ks, m, jax.random.fold_in(key, i))
+
+    def write(c0, c1, ct, lo):
+        return (jax.lax.dynamic_update_slice_in_dim(c0, ct.c0, lo, 0),
+                jax.lax.dynamic_update_slice_in_dim(c1, ct.c1, lo, 0))
+
+    enc = _jitted(ks, f"ingest_encrypt_fae{fae}", enc)
+    write = _jitted(ks, "ingest_write", write, donate_argnums=(0, 1))
+    shape = (N, ks.params.num_towers, ks.params.n)
+    c0 = jnp.zeros(shape, jnp.int64)
+    c1 = jnp.zeros(shape, jnp.int64)
+    for i in range(N // chunk):
+        lo = i * chunk
+        c0, c1 = write(c0, c1, enc(m[lo:lo + chunk], key, i), lo)
+    return Ciphertext(c0, c1)
+
+
+def encrypt_constant(ks: KeySet, value: int, count: int,
+                     key: jax.Array) -> Ciphertext:
+    """`count` fresh encryptions of `value` (sentinel and zero pads).
+    Past one ingest chunk they go through `encrypt_rows`, rounded up to
+    whole chunks: a single eager encryption of thousands of paper-bfv
+    rows holds several column-sized NTT intermediates at once."""
+    chunk = INGEST_CHUNK_ROWS
+    n = count if count <= chunk else -(-count // chunk) * chunk
+    ct = encrypt_rows(ks, jnp.full((n,), value, jnp.int64), key)
+    return ct if n == count else Ciphertext(ct.c0[:count], ct.c1[:count])
+
+
 def _zero_pad_rows(ks: KeySet, cname: str, n_pad: int,
                    salt: int) -> Ciphertext:
     """`n_pad` fresh public-key encryptions of 0 (append-path padding)."""
     key = jax.random.fold_in(column_key(jax.random.PRNGKey(_APPEND_PAD_SEED),
                                         cname), salt)
-    return E.encrypt(ks, jnp.zeros(n_pad, jnp.int64), key)
+    return encrypt_constant(ks, 0, n_pad, key)
 
 
 class Table:
@@ -165,7 +219,6 @@ class Table:
         n_rows = next(iter(lengths.values()))
         if any(v != n_rows for v in lengths.values()):
             raise ValueError(f"ragged input columns: {lengths}")
-        enc = E.encrypt_fae if fae else E.encrypt
         is_float = ks.params.profile.scheme == "ckks"
         columns = {}
         for cname, arr in data.items():
@@ -179,8 +232,8 @@ class Table:
             padded = pad_rows_pow2(
                 arr.astype(np.float64 if is_float else np.int64),
                 n_target=n_padded)
-            columns[cname] = enc(ks, jnp.asarray(padded),
-                                 column_key(key, cname))
+            columns[cname] = encrypt_rows(ks, jnp.asarray(padded),
+                                          column_key(key, cname), fae=fae)
         return cls(name, columns, n_rows)
 
     @classmethod
@@ -309,6 +362,14 @@ class Table:
         """Width of the union scan: base block + delta block slots."""
         return self.n_padded + (0 if self.delta is None
                                 else self.delta.n_padded)
+
+    def scan_parts(self, name: str) -> tuple:
+        """The named column's blocks in union-slot order: the base block,
+        then the delta block when a delta run is pending.  The fused
+        scan tiles each block in place, never concatenating them."""
+        if self.delta is None:
+            return (self.columns[name],)
+        return (self.columns[name], self.delta.columns[name])
 
     def scan_column(self, name: str) -> Ciphertext:
         """The named column over the UNION slot space — base block then

@@ -8,14 +8,12 @@ Computes, per batched ciphertext difference (d0, d1):
 entirely inside one kernel: pre-twist, DIF-NTT, MAC against the CEK held in
 br-eval order, DIT-INTT, post-twist, emit coefficient 0 only.
 
-Roofline motivation (EXPERIMENTS.md §Perf): the naive pipeline writes the
-full n-coefficient eval polynomial back to HBM (2*K*n*8 B per compare) and
-re-reads it to decode; the comparison *result* is one residue per tower.
-Fusing decode into the kernel cuts output bytes by n x (4096x for the paper
-profile), turning the compare plane from memory-bound to compute-bound.
-
-Same legality notes as kernels/ntt.py (no gathers, int64 MACs,
-interpret-mode validated; ref.py is the oracle).
+The kernel emits one residue per tower instead of the n-coefficient eval
+polynomial.  It computes on int64 vectors, which the TPU compiler refuses
+(see kernels/ops.py), so it runs in interpret mode on the CPU only, where
+ref.py is its oracle.  Its layout is not TPU-legal either: a (bb, 1, n)
+block over K=2 towers breaks the (8, 128) rule for the last two block
+dimensions.
 """
 from __future__ import annotations
 

@@ -1,21 +1,16 @@
-"""Pallas negacyclic-NTT kernels (TPU target, validated interpret=True).
+"""Pallas negacyclic-NTT kernels (interpret mode; validated against ref.py).
 
-TPU adaptation of the paper's OpenFHE CPU hot spot (DESIGN.md §3):
-
-* A whole ciphertext block stays **VMEM-resident across all log2(n) butterfly
-  stages** — one HBM round-trip per polynomial instead of one per stage.
-  n = 4096 coeffs x 8 B = 32 KiB/poly; a (8, n) block + twiddles is ~0.5 MiB,
-  far under the ~16 MiB VMEM budget.
-* **No bit-reversal gathers anywhere**: the forward transform is
-  decimation-in-frequency (natural -> bit-reversed "br-eval" order) and the
-  inverse is decimation-in-time (br-eval -> natural). Pointwise products are
-  order-agnostic, so the convolution pipeline never permutes. Gathers are the
-  one op class that maps badly onto the TPU vector unit; reshapes/rolls here
-  are lane-local.
-* Modular arithmetic: residues < 2^31 so a*b fits int64; `%` is exact in
-  interpret mode. Production-TPU note: int64 lowers to 32-bit pairs on TPU —
-  the drop-in fix is 16-bit limb decomposition with int32 MACs (jaxite-style),
-  which changes only the in-kernel `_mulmod` below, not the schedule.
+* A ciphertext block stays in VMEM across all log2(n) butterfly stages —
+  one HBM round-trip per polynomial instead of one per stage.
+* No bit-reversal gathers: the forward transform is decimation-in-frequency
+  (natural -> bit-reversed "br-eval" order) and the inverse is
+  decimation-in-time (br-eval -> natural); pointwise products are
+  order-agnostic, so the convolution pipeline never permutes.
+* Modular arithmetic: residues < 2^31 so a*b fits int64, and `%` is exact
+  in interpret mode.  The TPU compiler refuses int64 vectors in a Pallas
+  kernel (see kernels/ops.py), and a (bb, 1, n) block over K=2 towers
+  breaks its (8, 128) block rule, so these kernels do not run on a TPU.
+  A TPU version needs 32-bit residues (16-bit limbs with int32 MACs).
 
 Layout: polys are [B, K, n] (batch, RNS towers, coeffs). Grid = (B/bb, K);
 each program transforms a (bb, n) tile for one tower.

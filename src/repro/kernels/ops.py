@@ -1,9 +1,14 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public jit'd wrappers around the Pallas kernels, the lane-budget
+policy, and the shard_map eval entry.
 
-Handles batch padding (grid blocks need B % block_b == 0), backend dispatch
-(interpret=True on CPU, compiled on TPU), and exposes a kernel-backed
-`compare` with the same contract as core.compare — used by integration tests
-and the benchmark harness to demonstrate the fused-path speedup.
+Handles batch padding (grid blocks need B % block_b == 0) and exposes a
+kernel-backed `compare` with the same contract as core.compare, which
+tests cross-check against the jnp path.  The kernels run in interpret
+mode off the TPU.  On a TPU they are compiled, and the compiler refuses
+them: they compute on int64 vectors ("UNIMPLEMENTED: While rewriting
+computation to not contain X64 element types, XLA encountered an HLO for
+which this rewriting is not implemented: ... tpu_custom_call").  No
+served path reaches them there; engine="kernel" raises that error.
 """
 from __future__ import annotations
 
@@ -29,16 +34,47 @@ def _on_tpu() -> bool:
 # lane-budget policy: the one knob bounding every eval launch's working set
 # ---------------------------------------------------------------------------
 
-# Default ceiling on eval LANES per launch (one lane = one [K, n]
-# polynomial compare).  Every NTT-stage intermediate scales with the
-# lane count, so this is the working-set bound that keeps a launch on
-# the fast side of the cache/bandwidth cliff: the measured hg38 serving
-# regression (ROADMAP) showed [2, 65536]-lane launches ~2x faster per
-# lane than one [16, 65536] program — 1 << 17 is that fast regime's
-# size.  Scan tiles (`db.executor.fused_eval`) and join grid tiles
-# (`db.join.pair_eval_values`, via its own DEFAULT_BLOCK_PAIRS default)
-# both resolve through this policy, so one knob governs both.
+# Ceiling on eval LANES per launch (one lane = one [K, n] polynomial
+# compare).  Every eval intermediate scales with the lane count, so
+# this is the working-set bound of a launch.  1 << 17 was the fast
+# regime of a CPU host's cache (ROADMAP); on a device that reports its
+# memory, `device_lane_budget` cuts it to what fits.  Scan tiles
+# (`db.executor.fused_eval`, the sharded executor) and join grid tiles
+# (`db.join.pair_eval_values`) all resolve through this policy.
 DEFAULT_LANE_BUDGET = 1 << 17
+
+# share of device memory one eval launch's temporaries may take: the
+# table's columns and indexes live beside it
+EVAL_MEMORY_SHARE = 8
+
+
+def eval_lane_bytes(params) -> int:
+    """Upper bound on one eval lane's device temporaries, int64: 4x the
+    rows its coefficient-0 inner product reads (gadget: K·D digit rows;
+    paper: K tower rows), plus 4 K-tower rows for the lane's gathered
+    ciphertext pair and difference.  The TPU compiler's
+    `memory_analysis()` of a fused scan tile comes to 0.8x of it at
+    paper-bfv (tests/test_tpu_compile.py checks the bound)."""
+    rows = params.num_towers
+    if params.mode == "gadget":
+        rows *= params.gadget_digits_per_tower
+    return 8 * params.n * (4 * rows + 4 * params.num_towers)
+
+
+def device_lane_budget(params) -> int:
+    """The default lane budget: `DEFAULT_LANE_BUDGET`, cut so one
+    launch's temporaries (`eval_lane_bytes`) fit 1/EVAL_MEMORY_SHARE of
+    the device's memory where the backend reports it (a TPU does; the
+    CPU backend does not, and keeps the constant).  Outside this share:
+    the table itself, and the 32-bit plane of an int64 column block a
+    TPU's scan-tile slice holds for a moment (`db.executor.scan_tile`,
+    half the block's bytes, whatever the lane count)."""
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    if not limit:
+        return DEFAULT_LANE_BUDGET
+    fit = limit // EVAL_MEMORY_SHARE // eval_lane_bytes(params)
+    return int(max(1, min(DEFAULT_LANE_BUDGET, fit)))
 
 _LANE_BUDGET_OVERRIDE: int | None = None
 
@@ -194,23 +230,6 @@ def broadcast_eval_values(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext, *,
 # shard-aware eval entry (repro.db.shard)
 # ---------------------------------------------------------------------------
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map          # type: ignore[attr-defined]
-except ImportError:
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-
-def _ks_cache(ks: KeySet, name: str) -> dict:
-    """Per-KeySet jit cache (lifetime tied to the keyset, same pattern as
-    db/executor._jitted — duplicated here to keep kernels below db in the
-    layering)."""
-    cache = getattr(ks, name, None)
-    if cache is None:
-        cache = {}
-        object.__setattr__(ks, name, cache)
-    return cache
-
-
 def shard_eval_values(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext, *,
                       mesh, axis_name: str = "shard",
                       use_kernel: bool = False,
@@ -240,6 +259,22 @@ def shard_eval_values(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext, *,
     lanes against the replicated [A, 1] bounds.
     """
     interpret = (not _on_tpu()) if interpret is None else interpret
+    fn = _shard_eval_fn(ks, mesh, axis_name, use_kernel, interpret, block_b,
+                        ct0.c0.ndim, ct1.c0.ndim, sel is not None)
+    args = (ct0.c0, ct0.c1, ct1.c0, ct1.c1)
+    if sel is not None:
+        args += (jnp.asarray(sel),)
+    return fn(*args)
+
+
+def _shard_eval_fn(ks: KeySet, mesh, axis_name: str, use_kernel: bool,
+                   interpret: bool, block_b: int, nd0: int, nd1: int,
+                   with_sel: bool):
+    """The jitted `shard_map` program `shard_eval_values` launches,
+    cached per KeySet and signature (the compile rehearsal lowers it
+    directly)."""
+    from jax.sharding import PartitionSpec as P
+
     from repro.core import compare as C
 
     def local_eval(c00, c01, b0, b1, *sel_arg):
@@ -253,23 +288,18 @@ def shard_eval_values(ks: KeySet, ct0: Ciphertext, ct1: Ciphertext, *,
                                      Ciphertext(b0, b1),
                                      block_b=block_b, interpret=interpret)
 
-    from jax.sharding import PartitionSpec as P
-    nd0, nd1 = ct0.c0.ndim, ct1.c0.ndim
-    cache = _ks_cache(ks, "_shard_eval_cache")
+    cache = ks.cache("shard_eval")
     key = (id(mesh), axis_name, use_kernel, interpret, block_b, nd0, nd1,
-           sel is not None)
+           with_sel)
     if key not in cache:
         spec0 = P(axis_name, *([None] * (nd0 - 1)))
         rep = P(*([None] * nd1))
         in_specs = [spec0, spec0, rep, rep]
-        if sel is not None:
+        if with_sel:
             in_specs.append(P(None))         # gather indices: replicated
         out_spec = P(axis_name, *([None] * (nd0 - 3)))
-        fn = _shard_map(local_eval, mesh=mesh,
-                        in_specs=tuple(in_specs),
-                        out_specs=out_spec, check_rep=False)
+        fn = jax.shard_map(local_eval, mesh=mesh,
+                           in_specs=tuple(in_specs),
+                           out_specs=out_spec, check_vma=False)
         cache[key] = jax.jit(fn)
-    args = (ct0.c0, ct0.c1, ct1.c0, ct1.c1)
-    if sel is not None:
-        args += (jnp.asarray(sel),)
-    return cache[key](*args)
+    return cache[key]

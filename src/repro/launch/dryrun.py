@@ -237,11 +237,12 @@ def run_hades_cell(shape: str, multi_pod: bool) -> Dict:
     compact = shape.endswith("_c32")
     b_axes = SH.batch_axes(mesh)
 
-    def fn(cek_ntt, a0, a1, b0, b1):
+    def fn(cek_rev, a0, a1, b0, b1):
         if compact:
             a0, a1, b0, b1 = (t.astype(jnp.int64) for t in (a0, a1, b0, b1))
         ks = KeySet(params=params, ring=ring, sk=None, pk0=None, pk1=None,
-                    cek=None, cek_gadget=None, cek_gadget_ntt=cek_ntt)
+                    cek=None, cek_gadget=None, cek_gadget_ntt=None,
+                    cek_rev=cek_rev)
         return HC.compare(ks, Ciphertext(a0, a1), Ciphertext(b0, b1))
 
     ct_dt = jnp.int32 if compact else jnp.int64
@@ -259,12 +260,10 @@ def run_hades_cell(shape: str, multi_pod: bool) -> Dict:
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
         coll = RL.collective_bytes(compiled.as_text())
-    # "useful" op count: (E fwd NTTs + 1 inv NTT) x K towers of
-    # (n/2 log n) butterflies (~2 int-ops each) + E*K*n pointwise MACs,
-    # per comparison, per device.
+    # "useful" op count: the coefficient-0 inner product, E*K*n MACs
+    # (2 int-ops each) per comparison, per device.
     b_dev = B / (mesh.shape.get("pod", 1) * mesh.shape["data"])
-    log_n = n.bit_length() - 1
-    useful = b_dev * K * ((E + 1) * (n // 2) * log_n * 2 + E * n * 2)
+    useful = b_dev * K * E * n * 2
     flops = float(cost.get("flops", 0.0)) + float(
         cost.get("transcendentals", 0.0))
     # fused-kernel HBM floor: 4 ct components in + CEK + residues out

@@ -9,23 +9,21 @@ dimension; weights are never sharded across pods (DESIGN.md §5).
 """
 from __future__ import annotations
 
+import logging
 import math
 
 import jax
 
-try:  # jax >= 0.5 (explicit-sharding axis types)
-    from jax.sharding import AxisType
-except ImportError:  # the baked jax 0.4.x: every mesh axis is Auto already
-    AxisType = None
+from jax.sharding import AxisType
+
+log = logging.getLogger(__name__)
 
 
 def _mesh(shape, axes, devices=None):
-    """jax.make_mesh across jax versions: pass axis_types only when the
-    installed jax knows about them (0.4.x predates AxisType)."""
+    """jax.make_mesh with every axis Auto (sharding propagated by XLA)."""
     kw = {"devices": devices} if devices is not None else {}
-    if AxisType is not None:
-        kw["axis_types"] = (AxisType.Auto,) * len(axes)
-    return jax.make_mesh(shape, axes, **kw)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -51,21 +49,26 @@ def make_host_mesh(model_parallel: int = 1):
 def make_shard_mesh(num_shards: int, *, axis: str = "shard"):
     """1-D device mesh for `repro.db.shard` tables.
 
-    Shard count is LOGICAL (chosen by the table's `ShardSpec`); this
-    picks d = the largest divisor of `num_shards` the host can supply,
+    Shard count is LOGICAL (chosen by the table's `ShardSpec`): the mesh
+    takes d = the largest divisor of `num_shards` the host can supply,
     so a `[num_shards, ...]`-leading ciphertext stack always places
-    evenly — 4 shards run 4-way on a v5e slice, 2-way on a 2-device
-    host, and degrade to one device without any caller change.
+    evenly (4 shards run 4-way on a four-chip host and on one device of
+    a one-device host, with identical answers).  A mesh of fewer devices
+    than shards is logged as a warning, never built quietly; a caller
+    that needs a given device count checks `mesh.devices`.
     """
     if num_shards < 1:
         raise ValueError(f"num_shards must be >= 1, got {num_shards}")
-    devices = jax.devices()
+    have = jax.devices()
     d = 1
-    for cand in range(min(num_shards, len(devices)), 0, -1):
+    for cand in range(min(num_shards, len(have)), 0, -1):
         if num_shards % cand == 0:
             d = cand
             break
-    return _mesh((d,), (axis,), devices[:d])
+    if d < num_shards:
+        log.warning("shard mesh: %d shards over %d of %d devices",
+                    num_shards, d, len(have))
+    return _mesh((d,), (axis,), have[:d])
 
 
 # TPU v5e single-chip peaks (roofline constants; see brief)

@@ -478,3 +478,26 @@ def test_query_server_eps_band_lanes(scheme_ks, rng):
                                   np.abs(vals - target) <= EPS_BAND)
     np.testing.assert_array_equal(results[id2].mask,
                                   (vals >= lo) & (vals <= hi))
+
+
+def test_chunked_ingest_decrypts_and_matches_one_shot(bfv_engine_ks,
+                                                      monkeypatch):
+    """`encrypt_rows` over several chunks writes every row in place:
+    decrypts to the plaintext, and a column within one chunk is the
+    plain one-call encryption."""
+    from repro.db import table as T
+    ks = bfv_engine_ks
+    m = jnp.arange(16, dtype=jnp.int64) * 3 - 7
+    key = jax.random.PRNGKey(5)
+    monkeypatch.setattr(T, "INGEST_CHUNK_ROWS", 4)
+    ct = T.encrypt_rows(ks, m, key)
+    assert ct.c0.shape == (16, ks.params.num_towers, ks.params.n)
+    np.testing.assert_array_equal(np.asarray(E.decrypt(ks, ct)),
+                                  np.asarray(m))
+    with pytest.raises(ValueError):
+        T.encrypt_rows(ks, m[:14], key)
+    monkeypatch.setattr(T, "INGEST_CHUNK_ROWS", 16)
+    one = T.encrypt_rows(ks, m, key)
+    ref = E.encrypt(ks, m, key)
+    assert jnp.array_equal(one.c0, ref.c0) and jnp.array_equal(one.c1,
+                                                               ref.c1)

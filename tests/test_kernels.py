@@ -101,3 +101,33 @@ def test_kernel_eval_matches_core_eval_value(bfv_params, bfv_keys):
         CK.cek_gadget_to_br(bfv_keys), bfv_keys.ring, bfv_params.scale)
     got = R.crt_centered(bfv_params, coeff0[:2])
     assert jnp.array_equal(got, want)
+
+
+def test_shard_eval_values_on_one_device_mesh(bfv_keys):
+    """`jax.shard_map` as this JAX spells it: the shard-parallel eval on
+    a 1-device shard mesh equals the plain eval (the multi-device path
+    runs the same call)."""
+    from repro.launch.mesh import make_shard_mesh
+    ks = bfv_keys
+    m = jnp.arange(12, dtype=jnp.int64).reshape(2, 6) % 5
+    col = E.encrypt(ks, m, jax.random.PRNGKey(3))           # [S=2, 6]
+    b = E.encrypt(ks, jnp.asarray([1, 3], jnp.int64),
+                  jax.random.PRNGKey(4))
+    bounds = E.Ciphertext(b.c0[:, None], b.c1[:, None])      # [A=2, 1]
+    mesh = make_shard_mesh(1)
+    cols = E.Ciphertext(col.c0[:, None], col.c1[:, None])    # [S, 1, 6]
+    got = ops.shard_eval_values(ks, cols, bounds, mesh=mesh)
+    want = C.eval_value(ks, cols, bounds)
+    assert got.shape == (2, 2, 6)
+    assert jnp.array_equal(got, want)
+
+
+def test_shard_mesh_never_shrinks_quietly(caplog):
+    from repro.launch.mesh import make_shard_mesh
+    have = len(jax.devices())
+    mesh = make_shard_mesh(have)
+    assert len({d.id for d in mesh.devices.flat}) == have
+    with caplog.at_level("WARNING", logger="repro.launch.mesh"):
+        mesh = make_shard_mesh(2 * have + 1)     # no divisor but 1 fits
+    assert mesh.devices.size == 1
+    assert f"{2 * have + 1} shards over 1 of {have} devices" in caplog.text

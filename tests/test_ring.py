@@ -90,3 +90,62 @@ def test_all_profiles_constructible():
         p = make_params(name)
         assert p.max_operand > 0, name
         assert p.tau > 0
+
+
+@pytest.mark.parametrize("profile", ["test-bfv", "paper-bfv"])
+def test_division_free_reduction_matches_mod(profile):
+    """Barrett `reduce`, `reduce_signed` and the add/sub/mul helpers
+    give exactly what int64 `%` gives, at the edges of their ranges."""
+    qs = make_params(profile).qs
+    mus = R.barrett_mu(qs)
+    rng = np.random.default_rng(0)
+    for q, mu in zip(qs, mus):
+        x = np.concatenate([
+            rng.integers(0, 1 << 62, 4096, dtype=np.int64),
+            np.array([0, 1, q - 1, q, q + 1, 4 * q, (1 << 62) - 1,
+                      (q - 1) * (q - 1), ((1 << 62) // q) * q - 1],
+                     np.int64)])
+        assert np.array_equal(np.asarray(R.reduce(jnp.asarray(x), q,
+                                                  int(mu))), x % q)
+        s = np.concatenate([x, -x]) >> 1
+        assert np.array_equal(
+            np.asarray(R.reduce_signed(jnp.asarray(s), q, int(mu))), s % q)
+        a = rng.integers(0, q, 4096, dtype=np.int64)
+        b = np.concatenate([rng.integers(0, q, 4094, dtype=np.int64),
+                            [0, q - 1]])
+        ja, jb = jnp.asarray(a), jnp.asarray(b)
+        assert np.array_equal(np.asarray(R.mulmod(ja, jb, q, int(mu))),
+                              (a * b) % q)
+        assert np.array_equal(np.asarray(R.addmod(ja, jb, q)), (a + b) % q)
+        assert np.array_equal(np.asarray(R.submod(ja, jb, q)), (a - b) % q)
+
+
+def test_barrett_mu_rejects_moduli_outside_its_range():
+    with pytest.raises(ValueError):
+        R.barrett_mu([(1 << 30) - 35])
+
+
+@pytest.mark.parametrize("profile", ["test-bfv", "paper-bfv"])
+def test_crt_centered_matches_python_crt(profile):
+    """Garner CRT decode == the big-integer CRT, centered."""
+    params = make_params(profile)
+    qs, Q = params.qs, params.Q
+    rng = np.random.default_rng(1)
+    vals = [int(v) for v in rng.integers(-(Q // 2), Q // 2, 512,
+                                         dtype=np.int64)]
+    vals += [Q // 2, -(Q // 2) + 1, 0, 1, -1]
+    res = np.asarray([[v % q for q in qs] for v in vals], np.int64)
+    got = np.asarray(R.crt_centered(params, jnp.asarray(res)))
+    assert got.tolist() == vals
+
+
+def test_coeff0_reversal_gives_product_coefficient_zero(bfv_params, ring):
+    """sum_i a_i r_i == coefficient 0 of the negacyclic product a * b."""
+    k1, k2 = jax.random.split(jax.random.PRNGKey(11))
+    a = sampling.uniform_poly(bfv_params, k1, (3,))
+    b = sampling.uniform_poly(bfv_params, k2)
+    r = R.coeff0_reversal(ring, b)
+    want = R.negacyclic_mul(ring, a, b)[..., 0]
+    got = R.reduce(jnp.sum(R.pointwise_mul(ring, a, r), axis=-1),
+                   ring.q_arr[:, 0], ring.mu_arr[:, 0])
+    assert jnp.array_equal(got, want)
