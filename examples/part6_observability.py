@@ -2,9 +2,11 @@
 
 One encrypted range query (linear scan, then through the HADES sorted
 index) runs under a trace; the demo prints the nested span tree with
-device-true timings, the counter table the run produced, the jit-cache
+host timings, the counter table the run produced, the jit-cache
 observer's launch signatures, and writes a Chrome-trace JSON you can
-drop into ui.perfetto.dev.
+drop into ui.perfetto.dev.  The same run is also under `jax.profiler`:
+each span sits in its `.xplane.pb` beside the device's ops, carrying
+its `sid` and `parent`, and the demo lists them from there.
 
     PYTHONPATH=src python examples/part6_observability.py
     PYTHONPATH=src python examples/part6_observability.py \
@@ -37,6 +39,9 @@ def main(argv=None):
                     help="hg38 rows to load (0 = all 34,423)")
     ap.add_argument("--trace-out", default="obs_trace.json",
                     help="Chrome-trace JSON output path ('' = skip)")
+    ap.add_argument("--profile-dir", default="obs_profile",
+                    help="jax.profiler trace directory (TensorBoard / "
+                         "Perfetto via xprof)")
     args = ap.parse_args(argv)
 
     params = make_params("test-bfv", mode="gadget")
@@ -63,16 +68,26 @@ def main(argv=None):
 
     # ---- the traced run: linear scan, then the indexed path -------------
     print(f"\n--- traced: Range[{lo}, {hi}] linear + indexed ---")
-    with obs.tracing() as tr:
+    with jax.profiler.trace(args.profile_dir), obs.tracing() as tr:
         with obs.span("demo.linear"):
             lin = db.execute(ks, table, q)
         with obs.span("demo.indexed"):
             ind = db.execute(ks, table, q, indexes={"pos": idx})
     assert np.array_equal(lin.mask, ind.mask)
 
-    print("\nspan tree (device-true ms):")
+    print("\nspan tree (host ms; a span that reads a result back waits "
+          "for the device):")
     for line in tr.tree_lines():
         print(f"  {line}")
+
+    # the same spans in the profiler's trace, on the device's clock
+    prof = obs.profiler_spans(args.profile_dir)
+    t0 = prof[0]["start_ns"] if prof else 0
+    print(f"\nprofiler trace ({args.profile_dir}): {len(prof)} spans "
+          "(first 8; sid, parent, start and length in ms):")
+    for e in prof[:8]:
+        print(f"  {e['name']:<22} sid={e['sid']:<3} parent={e['parent']:<3} "
+              f"+{(e['start_ns'] - t0) / 1e6:8.3f}  {e['dur_ns'] / 1e6:8.3f}")
 
     print("\ncounter table:")
     snap = obs.REGISTRY.snapshot()

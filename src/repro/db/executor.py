@@ -253,7 +253,7 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
             for lo in range(0, Wb, T):
                 t = min(T, Wb - lo)
                 with obs.span("executor.eval_tile", offset=off + lo,
-                              rows=t) as tsp:
+                              rows=t):
                     obs.jit_launch("executor.fused_eval",
                                    (len(blk), t) + blk[0].c0.shape[1:],
                                    bounds.c0)
@@ -265,11 +265,10 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
                     if use_kernel:
                         col = Ciphertext(jnp.take(c0, sel_j, axis=0),
                                          jnp.take(c1, sel_j, axis=0))
-                        vals = tsp.sync(KO.broadcast_eval_values(ks, col,
-                                                                 bounds))
+                        vals = KO.broadcast_eval_values(ks, col, bounds)
                     else:
-                        vals = tsp.sync(jitted_dedup_eval(ks)(
-                            c0, c1, sel_j, bounds.c0, bounds.c1))
+                        vals = jitted_dedup_eval(ks)(
+                            c0, c1, sel_j, bounds.c0, bounds.c1)
                     out[:, off + lo:off + lo + t] = np.asarray(vals)
             off += Wb
         return out

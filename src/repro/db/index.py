@@ -134,21 +134,30 @@ class SortedIndex:
         probes = np.zeros(B, np.int64)
         with obs.span("index.search", column=self.column, lanes=B,
                       rows=self.n_rows) as sp:
+            step = 0
             while np.any(lo < hi):
                 active = lo < hi
-                mid = (lo + hi) // 2
-                probe = np.where(active, mid, 0)   # fixed shape; dead lanes
-                rows = Ciphertext(self.sorted_ct.c0[probe],
-                                  self.sorted_ct.c1[probe])
-                obs.jit_launch("index.probe", rows.c0, values.c0)
-                obs.count("eval.launches")
-                obs.count("eval.lanes", B)
-                v = np.asarray(ev(rows, values))              # [B] raw
-                c = np.where(np.abs(v) < taus, 0, np.sign(v))  # per-lane τ
-                probes += active
-                go_left = np.where(strict, c > 0, c >= 0)
-                hi = np.where(active & go_left, mid, hi)
-                lo = np.where(active & ~go_left, mid + 1, lo)
+                # step self time: the eager gather + the eval dispatch;
+                # decode: the device round trip + the host's bounds update
+                with obs.span("index.step", step=step,
+                              active=int(active.sum())):
+                    mid = (lo + hi) // 2
+                    probe = np.where(active, mid, 0)  # fixed shape; dead lanes
+                    rows = Ciphertext(self.sorted_ct.c0[probe],
+                                      self.sorted_ct.c1[probe])
+                    obs.jit_launch("index.probe", rows.c0, values.c0)
+                    obs.count("eval.launches")
+                    obs.count("eval.lanes", B)
+                    v = ev(rows, values)
+                    with obs.span("index.decode"):
+                        v = np.asarray(v)                      # [B] raw
+                        c = np.where(np.abs(v) < taus, 0,
+                                     np.sign(v))               # per-lane τ
+                        probes += active
+                        go_left = np.where(strict, c > 0, c >= 0)
+                        hi = np.where(active & go_left, mid, hi)
+                        lo = np.where(active & ~go_left, mid + 1, lo)
+                step += 1
             sp.set(probes=int(probes.sum()))
         obs.count("index.probes", int(probes.sum()))
         self.search_compares += int(probes.sum())

@@ -187,7 +187,7 @@ def pair_eval_values(ks: KeySet, left_ct: Ciphertext, right_ct: Ciphertext,
     use_kernel = X._use_kernel(engine)
     out = np.empty((L, R), dtype=np.int64)
     b = Ciphertext(right_ct.c0[None, :], right_ct.c1[None, :])   # [1, R, ...]
-    with obs.span("join.pair_grid", left=L, right=R, tile=T) as sp:
+    with obs.span("join.pair_grid", left=L, right=R, tile=T):
         for lo in range(0, L, T):
             a = Ciphertext(left_ct.c0[lo:lo + T, None],
                            left_ct.c1[lo:lo + T, None])          # [T, 1, ...]
@@ -197,9 +197,9 @@ def pair_eval_values(ks: KeySet, left_ct: Ciphertext, right_ct: Ciphertext,
             obs.count("eval.lanes", min(T, L - lo) * R)
             if use_kernel:
                 from repro.kernels import ops as KO
-                vals = sp.sync(KO.broadcast_eval_values(ks, a, b))
+                vals = KO.broadcast_eval_values(ks, a, b)
             else:
-                vals = sp.sync(X.jitted_eval(ks)(a, b))          # [T, R]
+                vals = X.jitted_eval(ks)(a, b)                   # [T, R]
             out[lo:lo + T] = np.asarray(vals)
             if stats is not None:
                 stats.eval_calls += 1
@@ -264,12 +264,12 @@ def merge_runs_to_pairs(ks: KeySet, runs: List[Tuple[Ciphertext, np.ndarray]],
         return np.zeros((0, 2), dtype=np.int64)
     mc0, mc1 = c0[keep], c1[keep]
     # ONE batched adjacency Eval: consecutive merged rows equal under τ?
-    with obs.span("join.adjacency", lanes=m - 1) as sp:
+    with obs.span("join.adjacency", lanes=m - 1):
         obs.jit_launch("join.adjacency", mc0[:-1])
         obs.count("eval.launches")
         obs.count("eval.lanes", m - 1)
-        v = np.asarray(sp.sync(X.jitted_eval(ks)(
-            Ciphertext(mc0[:-1], mc1[:-1]), Ciphertext(mc0[1:], mc1[1:]))))
+        v = np.asarray(X.jitted_eval(ks)(
+            Ciphertext(mc0[:-1], mc1[:-1]), Ciphertext(mc0[1:], mc1[1:])))
     stats.adjacency_compares += m - 1
     stats.eval_calls += 1
     eq_adj = np.abs(v) < tau
@@ -294,13 +294,13 @@ def merge_runs_to_pairs(ks: KeySet, runs: List[Tuple[Ciphertext, np.ndarray]],
         n_pad = C.next_pow2(n_cand)
         sel = np.concatenate([np.arange(n_cand),
                               np.zeros(n_pad - n_cand, np.int64)])
-        with obs.span("join.verify", candidates=n_cand, lanes=n_pad) as sp:
+        with obs.span("join.verify", candidates=n_cand, lanes=n_pad):
             lct = gather_left(pairs[sel, 0])
             rct = gather_right(pairs[sel, 1])
             obs.jit_launch("join.verify", lct.c0)
             obs.count("eval.launches")
             obs.count("eval.lanes", n_pad)
-            vv = np.asarray(sp.sync(X.jitted_eval(ks)(lct, rct)))[:n_cand]
+            vv = np.asarray(X.jitted_eval(ks)(lct, rct))[:n_cand]
         stats.verify_compares += n_pad
         stats.eval_calls += 1
         pairs = pairs[np.abs(vv) < tau]

@@ -287,7 +287,8 @@ class QueryServer:
 
     def _run_batch(self, chunk: List[Tuple[int, object]],
                    ) -> Dict[int, X.QueryResult]:
-        with obs.span("server.batch", size=len(chunk)) as bsp:
+        with obs.span("server.batch", size=len(chunk),
+                      qids=[qid for qid, _ in chunk]) as bsp:
             return self._run_batch_traced(chunk, bsp)
 
     def _run_batch_traced(self, chunk: List[Tuple[int, object]], bsp,
@@ -390,31 +391,36 @@ class QueryServer:
                                 lane_budget=self.lane_budget)
             bstats.eval_calls += 1
             bstats.scan_compares += len(scan_atoms) * W
-            for pi, li, start, count in scan_ref:
-                leaf_masks[pi][li] = X.scan_leaf_mask(ks, scan_atoms, vals,
-                                                      start, count)
-                qstats[pi].scan_leaves += 1
-                qstats[pi].scan_compares += count * W
-                qstats[pi].eval_calls = 1     # its share of the fused launch
 
-        # per-query combine + order/limit/project over the union slot
-        # space (join slots skip — their masks resolve inside the join
-        # section below); pads and tombstones drop via slot_valid
+        # the host tail after the launches: scan masks, then per-query
+        # combine + order/limit/project over the union slot space (join
+        # slots skip — their masks resolve inside the join section
+        # below); pads and tombstones drop via slot_valid
         results: Dict[int, X.QueryResult] = {}
-        for pi, (qid, plan) in enumerate(plans):
-            if qid is None:
-                continue
-            stats = qstats[pi]
-            slot_mask = X.combine_tree(plan.tree, leaf_masks[pi], W)
-            slot_mask &= table.slot_valid
-            row_ids = table.slot_global_ids[np.nonzero(slot_mask)[0]]
-            gmask = rows_to_mask(row_ids, table.n_total)
-            row_ids = X.order_rows(ks, table, plan.query, row_ids, stats)
-            columns = {c: table.gather(c, row_ids)
-                       for c in plan.query.select}
-            results[qid] = X.QueryResult(
-                row_ids=row_ids, mask=gmask, columns=columns, stats=stats)
-            self._bill_tenant(qid, stats)
+        with obs.span("server.decode", queries=len(queries)):
+            if scan_atoms:
+                for pi, li, start, count in scan_ref:
+                    leaf_masks[pi][li] = X.scan_leaf_mask(
+                        ks, scan_atoms, vals, start, count)
+                    qstats[pi].scan_leaves += 1
+                    qstats[pi].scan_compares += count * W
+                    qstats[pi].eval_calls = 1   # its share of the launch
+            for pi, (qid, plan) in enumerate(plans):
+                if qid is None:
+                    continue
+                stats = qstats[pi]
+                slot_mask = X.combine_tree(plan.tree, leaf_masks[pi], W)
+                slot_mask &= table.slot_valid
+                row_ids = table.slot_global_ids[np.nonzero(slot_mask)[0]]
+                gmask = rows_to_mask(row_ids, table.n_total)
+                row_ids = X.order_rows(ks, table, plan.query, row_ids,
+                                       stats)
+                columns = {c: table.gather(c, row_ids)
+                           for c in plan.query.select}
+                results[qid] = X.QueryResult(
+                    row_ids=row_ids, mask=gmask, columns=columns,
+                    stats=stats)
+                self._bill_tenant(qid, stats)
 
         if joins:
             with obs.span("server.joins", joins=len(joins)):

@@ -57,7 +57,9 @@ Observability (all no-ops unless `obs.tracing()` is active):
 `serve.queue_depth` histogram (depth at every admit and pump),
 `serve.queue_wait_s` histogram per class, `serve.rejected` /
 `serve.shed` / `serve.deadline_miss` / `serve.failed` per-tenant
-counters, and `serve.pump` / `serve.batch` spans around every drain.
+counters, and `serve.pump` / `serve.batch` spans around every drain
+(a `serve.batch` carries its drafted `tickets`, so a request's spans
+share its ticket).
 
 The loop is deterministic when driven synchronously: `pump()` runs one
 scheduling round, `run_until_idle()` pumps until the queue drains —
@@ -563,7 +565,8 @@ class ServeLoop:
         """Apply one mutation (isolated: a failing write resolves FAILED
         without poisoning the loop)."""
         server = reg.server
-        with obs.span("serve.batch", table=reg.name, klass=WRITE, size=1):
+        with obs.span("serve.batch", table=reg.name, klass=WRITE, size=1,
+                      tickets=[p.ticket]):
             self._mark_start([p], WRITE)
             try:
                 qid = self._submit_one(server, p)
@@ -596,7 +599,7 @@ class ServeLoop:
         self.batch_shapes.append((reg.name, klass, size))
         self.stats.batches += 1
         with obs.span("serve.batch", table=reg.name, klass=klass,
-                      size=size):
+                      size=size, tickets=[p.ticket for p in drafted]):
             self._mark_start(drafted, klass)
             try:
                 with server.batch_size(size):

@@ -85,7 +85,7 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
         out = np.empty((S, A, W), dtype=np.int64)
         for lo in range(0, W, T):
             t = min(T, W - lo)
-            with obs.span("shard.eval_tile", offset=lo, rows=t) as tsp:
+            with obs.span("shard.eval_tile", offset=lo, rows=t):
                 tile = Ciphertext(X.scan_tile(uniq.c0, lo, t, axis=2),
                                   X.scan_tile(uniq.c1, lo, t, axis=2))
                 obs.jit_launch("shard.fused_eval", tile.c0, bounds.c0)
@@ -93,18 +93,17 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
                 obs.count("eval.tiles")
                 obs.count("eval.lanes", S * A * t)
                 if spec.shard_map_ok:
-                    vals = tsp.sync(KO.shard_eval_values(
+                    vals = KO.shard_eval_values(
                         ks, tile, bounds, mesh=spec.mesh,
                         axis_name=spec.axis, use_kernel=use_kernel,
-                        sel=sel_j))
+                        sel=sel_j)
                 elif use_kernel:
                     col = Ciphertext(jnp.take(tile.c0, sel_j, axis=1),
                                      jnp.take(tile.c1, sel_j, axis=1))
-                    vals = tsp.sync(KO.broadcast_eval_values(ks, col,
-                                                             bounds))
+                    vals = KO.broadcast_eval_values(ks, col, bounds)
                 else:
-                    vals = tsp.sync(X.jitted_dedup_eval(ks, axis=1)(
-                        tile.c0, tile.c1, sel_j, bounds.c0, bounds.c1))
+                    vals = X.jitted_dedup_eval(ks, axis=1)(
+                        tile.c0, tile.c1, sel_j, bounds.c0, bounds.c1)
                 out[:, :, lo:lo + t] = np.asarray(vals)
         return out
 

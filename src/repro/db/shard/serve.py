@@ -207,7 +207,8 @@ class ShardedQueryServer:
     def _run_batch(self, chunk: List[Tuple[int, P.Query]],
                    ) -> Dict[int, X.QueryResult]:
         with obs.span("server.shard_batch", size=len(chunk),
-                      shards=self.stable.num_shards) as bsp:
+                      shards=self.stable.num_shards,
+                      qids=[qid for qid, _ in chunk]) as bsp:
             return self._run_batch_traced(chunk, bsp)
 
     def _run_batch_traced(self, chunk: List[Tuple[int, P.Query]], bsp,

@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import encrypt as E
 from repro.core.compare import next_pow2
 from repro.core.encrypt import Ciphertext
@@ -317,18 +318,21 @@ class Table:
             raise ValueError(
                 f"insert columns {sorted(data)} != table columns "
                 f"{sorted(self.columns)}")
-        new = Table.from_arrays(ks, f"{self.name}.delta", data, key)
-        start = self.n_total
-        if new.n_rows == 0:
-            return np.zeros(0, np.int64)
-        if self.delta is None:
-            self.delta = new
-        else:
-            self.delta = append_rows(ks, self.delta, new)
-        self._dead = np.concatenate(
-            [self._dead, np.zeros(new.n_rows, bool)])
-        self._invalidate()
-        return start + np.arange(new.n_rows, dtype=np.int64)
+        with obs.span("table.insert", table=self.name) as sp:
+            with obs.span("table.encrypt"):     # server-side, new rows only
+                new = Table.from_arrays(ks, f"{self.name}.delta", data, key)
+            sp.set(rows=new.n_rows)
+            start = self.n_total
+            if new.n_rows == 0:
+                return np.zeros(0, np.int64)
+            if self.delta is None:
+                self.delta = new
+            else:
+                self.delta = append_rows(ks, self.delta, new)
+            self._dead = np.concatenate(
+                [self._dead, np.zeros(new.n_rows, bool)])
+            self._invalidate()
+            return start + np.arange(new.n_rows, dtype=np.int64)
 
     def delete(self, rows) -> int:
         """Tombstone the given GLOBAL row ids (host-side mask; the

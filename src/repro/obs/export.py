@@ -1,7 +1,10 @@
-"""Exporters: Chrome-trace JSON, flat metrics dumps, BENCH fields.
+"""Exporters: Chrome-trace JSON, spans read back from a profiler
+trace, flat metrics dumps, BENCH fields.
 
 Two consumers: humans (load `write_chrome_trace` output into
-chrome://tracing or ui.perfetto.dev; print `tree_lines`), and the
+chrome://tracing or ui.perfetto.dev; print `tree_lines`; or open the
+`jax.profiler` trace, where the same spans sit beside the device's
+ops, and list them with `profiler_spans`), and the
 benchmark harness (`bench_fields()` rides each BENCH pass's `derived`
 dict so BENCH_db.json carries launch/lane/retrace counts across PRs).
 """
@@ -52,6 +55,35 @@ def validate_chrome_trace(doc: Any) -> List[str]:
                 if k not in ev:
                     errors.append(f"event {i}: complete event missing '{k}'")
     return errors
+
+
+def profiler_spans(trace_dir) -> List[Dict[str, Any]]:
+    """The spans a `jax.profiler` trace written under `trace_dir` holds
+    (newest `.xplane.pb`): each host event carrying a `sid` stat, as
+    `{name, start_ns, dur_ns, sid, parent}` in start order, times on
+    the profiler's clock (the device plane's)."""
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    paths = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    if not paths:
+        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
+    out = []
+    for plane in ProfileData.from_file(max(paths,
+                                           key=os.path.getmtime)).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                stats = dict(ev.stats)
+                if "sid" in stats:
+                    out.append({"name": ev.name, "start_ns": ev.start_ns,
+                                "dur_ns": ev.duration_ns,
+                                "sid": int(stats["sid"]),
+                                "parent": int(stats.get("parent", -1))})
+    return sorted(out, key=lambda e: e["start_ns"])
 
 
 def metrics_dump(registry: Optional[metrics.Registry] = None

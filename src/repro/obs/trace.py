@@ -1,8 +1,8 @@
 """Span tracing for the encrypted query engine.
 
-One global trace buffer, contextvar-nested spans, device-true timing.
-The design constraint is the disabled path: `obs.span(...)` must cost
-one global-bool check and return a shared no-op object, so
+One global trace buffer, contextvar-nested spans, and the profiler's
+clock.  The design constraint is the disabled path: `obs.span(...)`
+must cost one global-bool check and return a shared no-op object, so
 instrumentation can live inside the executor hot path permanently.
 
 Usage::
@@ -16,12 +16,17 @@ shard_map launches, index probes and compactions all attach to the
 span that was live when they started — including across threads
 spawned with a copied context.
 
-Device-true timing: jax dispatch is async, so a naive
-`perf_counter()` pair around a launch measures dispatch, not compute.
-`Span.sync(value)` calls `jax.block_until_ready` on the value *inside*
-the span when tracing is enabled, and is the identity function when
-disabled — enabling a trace tightens timing attribution without
-changing what the engine computes.
+One clock with the device: while tracing is enabled each span also
+opens a `jax.profiler.TraceAnnotation` under its own name, carrying its
+`sid` and its parent's `sid` (`parent`, -1 at a root) as event stats.
+Under `jax.profiler.trace` the spans therefore land in the `.xplane.pb`
+on the host's `python` line, on the same clock as the device plane's
+ops, so a stretch of device idle time can be put down to the innermost
+span open over it.  The span's other attributes stay in the `Tracer`,
+joined by `sid` (so attributes `set()` late still reach readers).  Device
+time is read from the profiler's device plane; a span never blocks on
+the device, so an enabled trace changes nothing the engine computes or
+when it waits.
 """
 from __future__ import annotations
 
@@ -31,6 +36,8 @@ import os
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _enabled: bool = os.environ.get("REPRO_OBS", "") not in ("", "0")
 
@@ -60,7 +67,7 @@ class Span:
     context manager.  Finished spans land in the global `Tracer`."""
 
     __slots__ = ("name", "args", "t0", "t1", "sid", "parent_sid",
-                 "depth", "tid", "_token")
+                 "depth", "tid", "_token", "_annotation")
 
     def __init__(self, name: str, args: Dict[str, Any]):
         self.name = name
@@ -72,6 +79,7 @@ class Span:
         self.depth = 0
         self.tid = 0
         self._token = None
+        self._annotation = None
 
     def __enter__(self) -> "Span":
         parent = _current.get()
@@ -83,11 +91,15 @@ class Span:
         self.depth = parent.depth + 1 if parent is not None else 0
         self.tid = threading.get_ident()
         self._token = _current.set(self)
+        self._annotation = TraceAnnotation(self.name, sid=self.sid,
+                                           parent=self.parent_sid)
+        self._annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         self.t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         if self._token is not None:
             _current.reset(self._token)
         TRACER._finish(self)
@@ -97,14 +109,6 @@ class Span:
         self.args.update(kw)
         return self
 
-    def sync(self, value):
-        """Block until `value` (a jax array / pytree) is device-ready,
-        so the span's duration includes the device work it launched.
-        Returns `value` unchanged."""
-        import jax
-        jax.block_until_ready(value)
-        return value
-
     @property
     def dur_s(self) -> float:
         """Span duration in seconds (0 until the span closes)."""
@@ -112,8 +116,8 @@ class Span:
 
 
 class _NullSpan:
-    """Shared do-nothing span handed out when tracing is disabled.
-    `sync` is the identity — no forced device sync on the fast path."""
+    """Shared do-nothing span handed out when tracing is disabled: no
+    allocation, no profiler call."""
 
     __slots__ = ()
 
@@ -126,10 +130,6 @@ class _NullSpan:
     def set(self, **kw) -> "_NullSpan":
         """No-op attribute setter (disabled-path stand-in)."""
         return self
-
-    def sync(self, value):
-        """Identity: no device sync when tracing is off."""
-        return value
 
 
 _NULL_SPAN = _NullSpan()
@@ -223,9 +223,12 @@ class Tracer:
 
 
 def _jsonable(v):
-    """Coerce span-attribute values to JSON-safe scalars."""
+    """Coerce span-attribute values to JSON-safe scalars (lists of
+    them for a list or tuple, such as a batch's tickets)."""
     if isinstance(v, (bool, int, float, str)) or v is None:
         return v
+    if isinstance(v, (list, tuple)):
+        return [_jsonable(x) for x in v]
     try:
         import numpy as np
         if isinstance(v, (np.integer,)):

@@ -50,7 +50,6 @@ def test_disabled_span_is_shared_noop_singleton():
     assert s1 is s2                      # no allocation on the hot path
     with s1 as sp:
         sp.set(y=2)                      # all no-ops
-        assert sp.sync(123) == 123       # identity, never blocks
     assert len(obs.TRACER.spans) == before
 
 
@@ -80,6 +79,50 @@ def test_span_nesting_parent_ids_and_depth():
     assert by_name["grandchild"].depth == 2
     for s in obs.TRACER.spans:
         assert s.t1 >= s.t0
+
+
+def test_spans_reach_the_profiler_trace(tmp_path):
+    """Under `jax.profiler`, each span is a host event of its own name in
+    the `.xplane.pb`, carrying the Tracer's `sid` and `parent`; args
+    (set late too) stay in the Tracer, joined by sid."""
+    f = jax.jit(lambda x: x * 2)
+    x = jax.numpy.ones(8)
+    f(x).block_until_ready()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.tracing() as tr:
+            with obs.span("root", k="v") as sp:
+                with obs.span("child"):
+                    with obs.span("grandchild"):
+                        f(x).block_until_ready()
+                with obs.span("child2"):
+                    pass
+                sp.set(late=1)
+    events = obs.profiler_spans(tmp_path)
+    assert [e["name"] for e in events] == ["root", "child", "grandchild",
+                                           "child2"]
+    by_sid = {s.sid: s for s in tr.spans}
+    for e in events:
+        s = by_sid[e["sid"]]
+        assert s.name == e["name"] and s.parent_sid == e["parent"]
+        assert e["dur_ns"] > 0
+    root = next(e for e in events if e["name"] == "root")
+    assert root["parent"] == -1
+    assert by_sid[root["sid"]].args == {"k": "v", "late": 1}
+    for e in events:                     # children lie inside their parent
+        if e["parent"] >= 0:
+            p = next(q for q in events if q["sid"] == e["parent"])
+            assert p["start_ns"] <= e["start_ns"]
+            assert (e["start_ns"] + e["dur_ns"]
+                    <= p["start_ns"] + p["dur_ns"])
+
+
+def test_disabled_spans_leave_no_profiler_event(tmp_path):
+    assert not obs.is_enabled()
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("quiet", x=1) as sp:
+            assert sp is obs.span("other")   # the shared null span
+            jax.numpy.ones(4).block_until_ready()
+    assert obs.profiler_spans(tmp_path) == []
 
 
 def test_tracing_context_restores_disabled_state():

@@ -627,6 +627,41 @@ def test_queue_depth_wait_and_spans_observed(bfv_engine_ks):
         assert obs.validate_chrome_trace(obs.chrome_trace()) == []
 
 
+def test_batch_spans_carry_their_requests(bfv_engine_ks):
+    """Each `serve.batch` span names the tickets it drafted (a write's,
+    its one ticket) and each `server.batch` under it the server's qids,
+    so a request's spans share an identifier."""
+    ks = bfv_engine_ks
+    _, _, pool = _env(ks)
+    loop = ServeLoop(batch=4)
+    loop.register("t", db.QueryServer(ks, _table(ks, name="t_ids"),
+                                      batch=4))
+    reads = [loop.submit("a", "t", db.Eq("v", pool[int(v)]))
+             for v in VALS[:3]]
+    w = loop.submit_insert("a", "t", {"v": np.array([41], np.int64)},
+                           jax.random.PRNGKey(9))
+    reads.append(loop.submit("a", "t", db.Eq("v", pool[15])))
+    with obs.tracing():
+        res = loop.run_until_idle()
+        spans = list(obs.TRACER.spans)
+    assert all(res[t].status == OK for t in reads + [w])
+    batches = [s for s in spans if s.name == "serve.batch"]
+    assert sorted(t for s in batches for t in s.args["tickets"]) == \
+        sorted(reads + [w])
+    assert [s.args["tickets"] for s in batches
+            if s.args["klass"] == WRITE] == [[w]]
+    for s in batches:
+        assert len(s.args["tickets"]) == s.args["size"]
+        if s.args["klass"] != WRITE:
+            kids = [k for k in spans if k.parent_sid == s.sid
+                    and k.name == "server.batch"]
+            assert sum(len(k.args["qids"]) for k in kids) == s.args["size"]
+    doc = obs.chrome_trace()
+    assert obs.validate_chrome_trace(doc) == []
+    assert any(ev["args"].get("tickets") == [w]
+               for ev in doc["traceEvents"])
+
+
 def test_jit_retraces_zero_in_steady_state(bfv_engine_ks):
     """Once a warmup wave has visited every pow2 bucket, an identical
     steady-state wave adds ZERO jit retraces — the bucketing's whole

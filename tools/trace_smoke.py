@@ -14,7 +14,10 @@ under `obs.tracing()`, then fails loudly unless:
     fused scan splits into lane tiles — every `executor.eval_tile`
     span must nest under an `executor.fused_eval` parent (the tiling
     must refine the launch accounting, never restructure the tree);
-  * per-query compare lanes reconcile exactly with the batch totals.
+  * per-query compare lanes reconcile exactly with the batch totals;
+  * the same spans sit in the `jax.profiler` trace of the batch (the
+    `.xplane.pb`, on one clock with the device's ops), each with the
+    `Tracer`'s `sid` and `parent`.
 
 The trace lands at --out (default trace_smoke.json) and CI uploads it
 as a workflow artifact, so every green run leaves an openable
@@ -27,6 +30,7 @@ from __future__ import annotations
 import argparse
 import json
 import sys
+import tempfile
 
 import jax
 import numpy as np
@@ -64,12 +68,21 @@ def main(argv=None) -> int:
             server.submit(db.Query(where=db.Range("v", enc(3, 5),
                                                   enc(95, 6)),
                                    top_k=db.TopK("v", 3)))]
-    with obs.tracing() as tr:
-        results = server.run()
-        spans = list(tr.spans)
-        tr.write_chrome_trace(args.out)
+    with tempfile.TemporaryDirectory() as prof:
+        with jax.profiler.trace(prof), obs.tracing() as tr:
+            results = server.run()
+            spans = list(tr.spans)
+            tr.write_chrome_trace(args.out)
+        in_profile = obs.profiler_spans(prof)
 
     errors = []
+
+    # the profiler's trace holds every span, with the Tracer's tree
+    want = {(s.sid, s.parent_sid, s.name) for s in spans}
+    got = {(e["sid"], e["parent"], e["name"]) for e in in_profile}
+    if got != want:
+        errors.append(f"profiler trace spans differ from the Tracer's: "
+                      f"{len(want - got)} missing, {len(got - want)} extra")
 
     # tile spans must NEST under the fused launch: the lane tiling is a
     # refinement of executor.fused_eval, not a sibling of it
@@ -113,7 +126,8 @@ def main(argv=None) -> int:
         print(f"FAIL {e}")
     if errors:
         return 1
-    print(f"trace smoke passed: {len(events)} events -> {args.out} "
+    print(f"trace smoke passed: {len(events)} events -> {args.out}, "
+          f"{len(in_profile)} spans in the profiler trace "
           f"(batch: {b.queries} queries, {b.eval_calls} fused launch, "
           f"{b.index_compares} probe + {b.scan_compares} scan lanes)")
     return 0
