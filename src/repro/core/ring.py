@@ -2,7 +2,10 @@
 
 Polynomials are int64 arrays of shape [..., K, n] (K = number of RNS towers),
 with residues kept in [0, q_k).  Every tower modulus is a prime in
-(2^30, 2^31), so a product of two residues fits a signed int64.
+(2^30, 2^31), so a product of two residues fits a signed int64, and a
+residue alone fits an int32: stored ciphertexts (a `db.Table`'s column
+blocks) are int32, and are widened to int64 before they reach any
+arithmetic here.
 
 This module owns modular arithmetic, and it never divides: an int64 `%`
 is a runtime 64-bit division, which the TPU emulates, and its expansion

@@ -48,7 +48,7 @@ from repro.core.encrypt import Ciphertext
 from repro.core.keys import KeySet
 from repro.db import plan as P
 from repro.db.index import SortedIndex
-from repro.db.table import Table, rows_to_mask
+from repro.db.table import Table, rows_to_mask, widen
 
 
 @dataclasses.dataclass
@@ -110,9 +110,12 @@ def jitted_eval(ks: KeySet):
 
 
 def jitted_dedup_eval(ks: KeySet, axis: int = 0):
-    """Jitted raw eval over a deduped column stack: gathers the unique
-    columns back to per-atom order (`jnp.take` by `sel` on `axis`)
-    INSIDE the program, then evaluates against the [A, 1] bounds.
+    """Jitted raw eval over a deduped column stack: widens the stack to
+    int64 (a stored tile is int32, `db.table.store`; the conversion is
+    the program's first operation, so it is tile-sized), gathers the
+    unique columns back to per-atom order (`jnp.take` by `sel` on
+    `axis`) INSIDE the program, then evaluates against the [A, 1]
+    bounds.
 
     The gather living inside the XLA program is the point — the host
     hands over U unique columns however many atoms alias them, and the
@@ -138,11 +141,13 @@ def jitted_dedup_eval(ks: KeySet, axis: int = 0):
 
     if ks.params.mode == "paper":
         def fn(uc0, uc1, sel, b0, b1):
+            uc0, uc1 = widen(Ciphertext(uc0, uc1))
             g_col = jnp.take(g0(uc0, uc1), sel, axis=axis)
             diff = R.submod(g_col, g0(b0, b1), ks.ring.q_arr[:, 0])
             return R.crt_centered(ks.params, diff)
     else:
         def fn(uc0, uc1, sel, b0, b1):
+            uc0, uc1 = widen(Ciphertext(uc0, uc1))
             col = Ciphertext(jnp.take(uc0, sel, axis=axis),
                              jnp.take(uc1, sel, axis=axis))
             return C.eval_value(ks, col, Ciphertext(b0, b1))
@@ -150,11 +155,13 @@ def jitted_dedup_eval(ks: KeySet, axis: int = 0):
 
 
 def scan_tile(x: jax.Array, lo: int, t: int, axis: int = 0) -> jax.Array:
-    """Rows [lo, lo + t) of a column block along its row `axis`: the one
-    way both executors cut a scan tile.  An eager slice (one compiled
-    slice per shape and t; the offset is an operand), so the eval
-    program takes only the tile.  On a TPU the slice program holds one
-    32-bit plane of the int64 block while it splits it into words."""
+    """Rows [lo, lo + t) of a stored (int32) column block along its row
+    `axis`: the one way both executors cut a scan tile.  An eager slice
+    (one compiled slice per shape and t; the offset is an operand), so
+    the eval program takes only the tile and widens it.  An int32 block
+    is read in place: the slice holds no temporary (on a TPU an int64
+    block would be split into two 32-bit planes whole, for every
+    tile)."""
     return jax.lax.dynamic_slice_in_dim(x, lo, t, axis=axis)
 
 
@@ -212,9 +219,10 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
     Duplicate-free and working-set bounded: each DISTINCT column is read
     ONCE ([U, N] bytes moved, not [A, N] — K range queries over one
     column used to ship K full copies): each tile is cut from the
-    column blocks (`scan_tile`) and stacked over the U columns, and the
-    per-atom gather and the [A, 1] bounds broadcast happen INSIDE the
-    jitted program, so no launch copies a column.  The
+    stored int32 column blocks (`scan_tile`) and stacked over the U
+    columns, and the widening to int64, the per-atom gather and the
+    [A, 1] bounds broadcast happen INSIDE the jitted program, so no
+    launch copies or converts a column.  The
     base block and a pending delta block tile separately, each into
     power-of-two chunks of T rows with A·T lanes within the lane budget
     (`kernels.ops.lane_tile`; explicit `lane_budget` > `set_lane_budget`
@@ -237,11 +245,11 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
         sel = np.asarray([order[a.column] for a in atoms], np.int64)
         parts = [table.scan_parts(c) for c in order]   # [U][block]
         bounds = stack_atom_bounds(atoms)
-        # host<->device traffic is the deduped reality: U unique columns
+        # bytes read are the deduped reality: U unique stored columns
         # + A bounds, counted once however many tiles launch
-        row_bytes = bounds.c0.nbytes // A
+        col_bytes = sum(b.c0.nbytes for b in parts[0])
         obs.count("bytes.moved",
-                  2 * (len(order) * W * row_bytes + bounds.c0.nbytes))
+                  2 * (len(order) * col_bytes + bounds.c0.nbytes))
         use_kernel = _use_kernel(engine)
         default = KO.device_lane_budget(ks.params)
         sel_j = jnp.asarray(sel)
@@ -263,6 +271,7 @@ def fused_eval(ks: KeySet, table: Table, atoms: List[P.Atom], *,
                     c0 = jnp.stack([scan_tile(c.c0, lo, t) for c in blk])
                     c1 = jnp.stack([scan_tile(c.c1, lo, t) for c in blk])
                     if use_kernel:
+                        c0, c1 = widen(Ciphertext(c0, c1))
                         col = Ciphertext(jnp.take(c0, sel_j, axis=0),
                                          jnp.take(c1, sel_j, axis=0))
                         vals = KO.broadcast_eval_values(ks, col, bounds)

@@ -39,6 +39,7 @@ from repro.db import executor as X
 from repro.db import plan as P
 from repro.db.shard import merge as M
 from repro.db.shard.table import ShardedTable
+from repro.db.table import widen
 
 
 @dataclasses.dataclass
@@ -62,11 +63,11 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
     contract as `db.executor.fused_eval`).
 
     Same dedup + lane-tiling discipline as the single-table scan: each
-    DISTINCT column's shard stack moves once ([S, U, N] bytes), the
-    per-atom gather runs inside the program (under `shard_map` on a
-    usable mesh — `sel` rides as a replicated operand), and the shard
-    row axis tiles into power-of-two chunks with S·A·T lanes within the
-    lane budget."""
+    DISTINCT column's stored (int32) shard stack moves once ([S, U, N]
+    bytes), the widening to int64 and the per-atom gather run inside
+    the program (under `shard_map` on a usable mesh — `sel` rides as a
+    replicated operand), and the shard row axis tiles into power-of-two
+    chunks with S·A·T lanes within the lane budget."""
     from repro.kernels import ops as KO
     with obs.span("shard.fused_eval", shards=stable.num_shards,
                   atoms=len(atoms), rows=stable.shard_scan_width) as sp:
@@ -98,8 +99,9 @@ def sharded_fused_eval(ks: KeySet, stable: ShardedTable,
                         axis_name=spec.axis, use_kernel=use_kernel,
                         sel=sel_j)
                 elif use_kernel:
-                    col = Ciphertext(jnp.take(tile.c0, sel_j, axis=1),
-                                     jnp.take(tile.c1, sel_j, axis=1))
+                    wide = widen(tile)
+                    col = Ciphertext(jnp.take(wide.c0, sel_j, axis=1),
+                                     jnp.take(wide.c1, sel_j, axis=1))
                     vals = KO.broadcast_eval_values(ks, col, bounds)
                 else:
                     vals = X.jitted_dedup_eval(ks, axis=1)(

@@ -41,6 +41,7 @@ from repro.db import plan as P
 from repro.db.shard import executor as SX
 from repro.db.shard.index import ShardedIndex
 from repro.db.shard.table import ShardedTable
+from repro.db.table import widen
 
 
 def _as_sharded(ks: KeySet, table) -> ShardedTable:
@@ -74,7 +75,7 @@ def sharded_pair_eval(ks: KeySet, left: ShardedTable, right: ShardedTable,
     reuses the tiled single-table launches.  Either way, thresholds are
     NOT applied here (the `fused_eval` raw-value contract)."""
     block_pairs = J._resolve_block_pairs(ks, block_pairs)
-    lct, rct = left.columns[lcol], right.columns[rcol]
+    lct, rct = widen(left.columns[lcol]), widen(right.columns[rcol])
     S_l, N_l = lct.c0.shape[:2]
     S_r, N_r = rct.c0.shape[:2]
     spec = left.spec

@@ -176,7 +176,7 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
     shard s alone (`num_blocks=1, pad_salt=s`) gets the pads it would
     get in the stack.
     """
-    from repro.db.table import encrypt_constant
+    from repro.db.table import encrypt_constant, widen
     pad_key = jax.random.PRNGKey(0x5A4D)
     c0s, c1s, ids = [], [], []
     for s in range(num_blocks):
@@ -187,8 +187,9 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
         parts0 = [ct.c0] if m else []
         parts1 = [ct.c1] if m else []
         if m < block:
-            pad = encrypt_constant(ks, pad_value, block - m,
-                                   jax.random.fold_in(pad_key, pad_salt + s))
+            pad = widen(encrypt_constant(
+                ks, pad_value, block - m,
+                jax.random.fold_in(pad_key, pad_salt + s)))
             parts0.append(pad.c0)
             parts1.append(pad.c1)
         c0s.append(jnp.concatenate(parts0) if len(parts0) > 1 else parts0[0])

@@ -41,7 +41,7 @@ from repro.core.encrypt import Ciphertext
 from repro.core.keys import KeySet
 from repro.db.shard.spec import ShardSpec
 from repro.db.table import (Table, append_rows, concat_ct_rows,
-                            encrypt_constant)
+                            encrypt_constant, widen)
 
 # compaction-fold pad rows (encryptions of 0) derive keys from this seed
 _FOLD_PAD_SEED = 0xC0FD
@@ -60,7 +60,10 @@ def partition_offsets(n_rows: int, num_shards: int) -> np.ndarray:
 
 
 class ShardedTable:
-    """Stacked encrypted columns `[S, N_sp, ...]` + partition bookkeeping."""
+    """Stacked encrypted columns `[S, N_sp, ...]` + partition bookkeeping.
+    The stacks hold the shards' `Table` blocks in their storage form
+    (int32, `db.table.store`); every accessor below but `scan_stack`
+    returns int64."""
 
     def __init__(self, name: str, columns: Dict[str, Ciphertext],
                  offsets: np.ndarray, spec: ShardSpec):
@@ -456,23 +459,24 @@ class ShardedTable:
         raise ValueError(f"shard {s} is not addressable here")
 
     def shard_block(self, name: str, s: int) -> Ciphertext:
-        """Shard s's BASE block [N_sp, K, n], read where it lives: on a
-        mesh, from the device holding shard s (no cross-device
-        program touches the rest of the stack)."""
+        """Shard s's BASE block [N_sp, K, n] as int64, read where it
+        lives: on a mesh, from the device holding shard s (no
+        cross-device program touches the rest of the stack)."""
         ct = self.columns[name]
         if self.spec.shard_devices() is None:
-            return Ciphertext(ct.c0[s], ct.c1[s])
+            return widen(Ciphertext(ct.c0[s], ct.c1[s]))
         (d0, i), (d1, _) = self._piece(ct.c0, s), self._piece(ct.c1, s)
-        return Ciphertext(d0[i], d1[i])
+        return widen(Ciphertext(d0[i], d1[i]))
 
     def _base_rows(self, name: str, s, pos) -> Ciphertext:
-        """Rows pos[j] of shard s[j]'s BASE block.  On a mesh, each
-        shard's rows are gathered on the device holding it and brought
-        to the default device — a gather over the sharded stack could
-        have the compiler assemble the whole stack on one device."""
+        """Rows pos[j] of shard s[j]'s BASE block, as int64.  On a mesh,
+        each shard's rows are gathered on the device holding it and
+        brought to the default device — a gather over the sharded stack
+        could have the compiler assemble the whole stack on one
+        device."""
         ct = self.columns[name]
         if self.spec.shard_devices() is None:
-            return Ciphertext(ct.c0[s, pos], ct.c1[s, pos])
+            return widen(Ciphertext(ct.c0[s, pos], ct.c1[s, pos]))
         s, pos = np.asarray(s, np.int64), np.asarray(pos, np.int64)
         home = jax.devices()[0]
         order, parts0, parts1 = [], [], []
@@ -483,22 +487,24 @@ class ShardedTable:
                 data, i = self._piece(x, int(sh))
                 parts.append(jax.device_put(data[i, pos[sel]], home))
         if not order:
-            empty = jnp.zeros((0,) + ct.c0.shape[2:], ct.c0.dtype)
+            empty = jnp.zeros((0,) + ct.c0.shape[2:], jnp.int64)
             return Ciphertext(empty, empty)
         inv = np.argsort(np.concatenate(order))
-        return Ciphertext(jnp.concatenate(parts0)[inv],
-                          jnp.concatenate(parts1)[inv])
+        return widen(Ciphertext(jnp.concatenate(parts0)[inv],
+                                jnp.concatenate(parts1)[inv]))
 
     def gather(self, name: str, s: int, local_rows) -> Ciphertext:
-        """Ciphertext rows of shard s's BASE block at local slots."""
+        """Ciphertext rows of shard s's BASE block at local slots (int64)."""
         idx = np.asarray(local_rows, np.int64)
         return self._base_rows(name, np.full(idx.shape, s, np.int64), idx)
 
     def scan_stack(self, name: str) -> Ciphertext:
         """The named column over the UNION scan: `[S, shard_scan_width,
-        ...]` — each shard's base block then its delta run, zero-padded
-        to the common delta block (pad lanes are never decoded: the
-        per-shard validity masks them before any host-side threshold).
+        ...]`, stored int32 (the sharded scan widens each tile inside
+        its eval program) — each shard's base block then its delta run,
+        zero-padded to the common delta block (pad lanes are never
+        decoded: the per-shard validity masks them before any host-side
+        threshold).
         With no pending delta this is the base stack unchanged, so the
         fused launch shape — and its jit cache entry — is stable across
         the compacted steady state."""
@@ -524,16 +530,16 @@ class ShardedTable:
             jnp.concatenate([ct.c1, jnp.stack(dc1s)], axis=1))
 
     def gather_global(self, name: str, global_rows) -> Ciphertext:
-        """Ciphertext rows at GLOBAL row ids (cross-shard projection;
-        resolves base slots and pending delta rows alike)."""
+        """Ciphertext rows at GLOBAL row ids, as int64 (cross-shard
+        projection; resolves base slots and pending delta rows alike)."""
         gids = np.asarray(global_rows, np.int64)
         ct = self.columns[name]
         s, pos = self._gid_shard[gids], self._gid_pos[gids]
         in_delta = self._gid_in_delta[gids]
         if not in_delta.any():
             return self._base_rows(name, s, pos)
-        c0 = jnp.zeros((gids.size,) + ct.c0.shape[2:], ct.c0.dtype)
-        c1 = jnp.zeros((gids.size,) + ct.c1.shape[2:], ct.c1.dtype)
+        c0 = jnp.zeros((gids.size,) + ct.c0.shape[2:], jnp.int64)
+        c1 = jnp.zeros((gids.size,) + ct.c1.shape[2:], jnp.int64)
         bi = np.nonzero(~in_delta)[0]
         if bi.size:
             base = self._base_rows(name, s[bi], pos[bi])
@@ -551,9 +557,9 @@ class ShardedTable:
         global id space in id order (pending delta rows included;
         tombstoned rows included — filter with `alive`)."""
         ct = self.columns[name]
-        vals = np.asarray(E.decrypt(
-            ks, Ciphertext(ct.c0.reshape((-1,) + ct.c0.shape[2:]),
-                           ct.c1.reshape((-1,) + ct.c1.shape[2:]))))
+        vals = np.asarray(E.decrypt(ks, widen(
+            Ciphertext(ct.c0.reshape((-1,) + ct.c0.shape[2:]),
+                       ct.c1.reshape((-1,) + ct.c1.shape[2:])))))
         vals = vals.reshape(self.num_shards, self.n_padded_per_shard)
         out = np.zeros(self.n_total, vals.dtype)
         base = ~self._gid_in_delta

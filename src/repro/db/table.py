@@ -8,10 +8,20 @@ results.  The pad rows are real encryptions of 0 — the server cannot
 distinguish them from data rows by inspection, only the table's public
 row count reveals the split.
 
+STORAGE.  Column blocks, base and delta, live on the device as int32
+residues (`store`): every tower modulus lies below 2^31, so int32 holds
+a ciphertext exactly in half the bytes of the int64 that `core.ring`
+computes in.  The fused scan cuts its tiles from the int32 blocks
+(`scan_parts`) and widens them inside the eval program; every other
+reader (`gather`, `column`, `scan_column`, `decrypt_column`) gets int64
+(`widen`).  On a TPU an int64 array is a pair of 32-bit planes, and a
+program that takes an int64 block splits the whole block, whatever part
+of it the program reads; an int32 block is read in place.
+
 Encryption is batched and chunked: `encrypt_rows` encrypts a column
-`INGEST_CHUNK_ROWS` rows per launch straight into its preallocated
+`INGEST_CHUNK_ROWS` rows per launch straight into its preallocated int32
 device buffers, so ingest peaks at the column plus one chunk of
-intermediates (a paper-bfv hg38 column alone is 8 GiB).
+intermediates (a paper-bfv hg38 column alone is 4 GiB).
 
 WRITE PATH.  A table is mutable through `insert` / `update` / `delete`:
 
@@ -35,6 +45,7 @@ through the log-depth merge network) — see that module.
 """
 from __future__ import annotations
 
+import functools
 import zlib
 from typing import Dict, Iterable, Optional
 
@@ -102,6 +113,36 @@ def pad_rows_pow2(arr: np.ndarray, *, n_target: Optional[int] = None,
     return padded
 
 
+def store(ct: Ciphertext) -> Ciphertext:
+    """The storage form of a ciphertext: int32 residues (exact, as every
+    residue lies in [0, q) with q < 2^31).  A no-op on int32."""
+    return Ciphertext(ct.c0.astype(jnp.int32), ct.c1.astype(jnp.int32))
+
+
+def widen(ct: Ciphertext) -> Ciphertext:
+    """The int64 form `core.ring` arithmetic takes.  A no-op on int64."""
+    return Ciphertext(ct.c0.astype(jnp.int64), ct.c1.astype(jnp.int64))
+
+
+@jax.jit
+def _take_rows(ct: Ciphertext, idx: jax.Array) -> Ciphertext:
+    """Rows `idx` of a stored block, widened to int64: one program per
+    shape, the gather and the conversion together."""
+    return widen(Ciphertext(ct.c0[idx], ct.c1[idx]))
+
+
+@functools.partial(jax.jit, donate_argnums=(0, 1))
+def ingest_write(c0: jax.Array, c1: jax.Array, ct: Ciphertext,
+                 lo) -> tuple:
+    """Write one encrypted chunk into the donated int32 column buffers at
+    row `lo`, narrowing it as it is written: no int64 column is ever
+    allocated."""
+    return (jax.lax.dynamic_update_slice_in_dim(
+                c0, ct.c0.astype(jnp.int32), lo, 0),
+            jax.lax.dynamic_update_slice_in_dim(
+                c1, ct.c1.astype(jnp.int32), lo, 0))
+
+
 def concat_ct_rows(*cts: Ciphertext) -> Ciphertext:
     """Concatenate ciphertext row stacks along the leading (row) dim —
     the ciphertext-level append used by delta growth, compaction and the
@@ -112,17 +153,17 @@ def concat_ct_rows(*cts: Ciphertext) -> Ciphertext:
 
 def encrypt_rows(ks: KeySet, m: jax.Array, key: jax.Array, *,
                  fae: bool = False) -> Ciphertext:
-    """Encrypt a [N] plaintext column on the device,
-    `INGEST_CHUNK_ROWS` rows per launch.  A column of at most one chunk
-    is one `encrypt` call under `key`; a longer one (N a multiple of the
-    chunk) is written chunk by chunk into preallocated [N, K, n] buffers
-    (donated, so updated in place), chunk i encrypting under
-    `fold_in(key, i)`.  The encrypt program depends on the chunk size
-    only, not on N."""
+    """Encrypt a [N] plaintext column on the device into its storage
+    form (`store`), `INGEST_CHUNK_ROWS` rows per launch.  A column of at
+    most one chunk is one `encrypt` call under `key`; a longer one (N a
+    multiple of the chunk) is written chunk by chunk into preallocated
+    int32 [N, K, n] buffers (`ingest_write`: donated, so updated in
+    place), chunk i encrypting under `fold_in(key, i)`.  The encrypt
+    program depends on the chunk size only, not on N."""
     chunk = INGEST_CHUNK_ROWS
     N = int(m.shape[0])
     if N <= chunk:
-        return (E.encrypt_fae if fae else E.encrypt)(ks, m, key)
+        return store((E.encrypt_fae if fae else E.encrypt)(ks, m, key))
     if N % chunk:
         raise ValueError(f"{N} rows do not split into chunks of {chunk}")
     from repro.db.executor import _jitted     # circular at module scope
@@ -131,27 +172,23 @@ def encrypt_rows(ks: KeySet, m: jax.Array, key: jax.Array, *,
         return (E.encrypt_fae if fae else E.encrypt)(
             ks, m, jax.random.fold_in(key, i))
 
-    def write(c0, c1, ct, lo):
-        return (jax.lax.dynamic_update_slice_in_dim(c0, ct.c0, lo, 0),
-                jax.lax.dynamic_update_slice_in_dim(c1, ct.c1, lo, 0))
-
     enc = _jitted(ks, f"ingest_encrypt_fae{fae}", enc)
-    write = _jitted(ks, "ingest_write", write, donate_argnums=(0, 1))
     shape = (N, ks.params.num_towers, ks.params.n)
-    c0 = jnp.zeros(shape, jnp.int64)
-    c1 = jnp.zeros(shape, jnp.int64)
+    c0 = jnp.zeros(shape, jnp.int32)
+    c1 = jnp.zeros(shape, jnp.int32)
     for i in range(N // chunk):
         lo = i * chunk
-        c0, c1 = write(c0, c1, enc(m[lo:lo + chunk], key, i), lo)
+        c0, c1 = ingest_write(c0, c1, enc(m[lo:lo + chunk], key, i), lo)
     return Ciphertext(c0, c1)
 
 
 def encrypt_constant(ks: KeySet, value: int, count: int,
                      key: jax.Array) -> Ciphertext:
-    """`count` fresh encryptions of `value` (sentinel and zero pads).
-    Past one ingest chunk they go through `encrypt_rows`, rounded up to
-    whole chunks: a single eager encryption of thousands of paper-bfv
-    rows holds several column-sized NTT intermediates at once."""
+    """`count` fresh encryptions of `value` (sentinel and zero pads), in
+    storage form.  They go through `encrypt_rows`, past one ingest chunk
+    rounded up to whole chunks: a single eager encryption of thousands
+    of paper-bfv rows holds several column-sized NTT intermediates at
+    once."""
     chunk = INGEST_CHUNK_ROWS
     n = count if count <= chunk else -(-count // chunk) * chunk
     ct = encrypt_rows(ks, jnp.full((n,), value, jnp.int64), key)
@@ -167,7 +204,8 @@ def _zero_pad_rows(ks: KeySet, cname: str, n_pad: int,
 
 
 class Table:
-    """Named encrypted columns + row-count bookkeeping + delta-run state."""
+    """Named encrypted columns + row-count bookkeeping + delta-run state.
+    Every column block is held in storage form (`store`, int32)."""
 
     def __init__(self, name: str, columns: Dict[str, Ciphertext],
                  n_rows: int):
@@ -185,7 +223,7 @@ class Table:
         if not (0 <= n_rows <= n_padded):
             raise ValueError(f"n_rows {n_rows} outside [0, {n_padded}]")
         self.name = name
-        self.columns = dict(columns)
+        self.columns = {c: store(ct) for c, ct in columns.items()}
         self.n_rows = int(n_rows)
         # -- write-path state (all host-side) --------------------------
         self.delta: Optional["Table"] = None     # pending insert run
@@ -368,9 +406,11 @@ class Table:
                                 else self.delta.n_padded)
 
     def scan_parts(self, name: str) -> tuple:
-        """The named column's blocks in union-slot order: the base block,
-        then the delta block when a delta run is pending.  The fused
-        scan tiles each block in place, never concatenating them."""
+        """The named column's stored int32 blocks in union-slot order:
+        the base block, then the delta block when a delta run is
+        pending.  The fused scan tiles each block in place, never
+        concatenating them, and widens each tile inside its eval
+        program."""
         if self.delta is None:
             return (self.columns[name],)
         return (self.columns[name], self.delta.columns[name])
@@ -378,11 +418,12 @@ class Table:
     def scan_column(self, name: str) -> Ciphertext:
         """The named column over the UNION slot space — base block then
         delta block, concatenated ciphertext rows (what the fused filter
-        launch scans, so base and delta ride ONE raw-eval program)."""
+        launch scans, so base and delta ride ONE raw-eval program), as
+        int64."""
         ct = self.columns[name]
         if self.delta is None:
-            return ct
-        return concat_ct_rows(ct, self.delta.columns[name])
+            return widen(ct)
+        return widen(concat_ct_rows(ct, self.delta.columns[name]))
 
     @property
     def slot_global_ids(self) -> np.ndarray:
@@ -424,22 +465,22 @@ class Table:
     # -- access ------------------------------------------------------------
 
     def column(self, name: str) -> Ciphertext:
-        """The named column's stacked BASE ciphertext rows (see
+        """The named column's stacked BASE ciphertext rows, as int64 (see
         `scan_column` for the base ∪ delta view)."""
-        return self.columns[name]
+        return widen(self.columns[name])
 
     def gather(self, name: str, rows: Iterable[int]) -> Ciphertext:
-        """Ciphertext rows of `name` at GLOBAL row ids — ids past
-        `n_rows` resolve into the delta run."""
+        """Ciphertext rows of `name` at GLOBAL row ids, as int64 — ids
+        past `n_rows` resolve into the delta run."""
         idx = np.asarray(rows, dtype=np.int64)
         ct = self.columns[name]
         if self.delta is None or idx.size == 0 or (idx < self.n_rows).all():
-            return Ciphertext(ct.c0[idx], ct.c1[idx])
+            return _take_rows(ct, idx)
         dct = self.delta.columns[name]
         bi = np.nonzero(idx < self.n_rows)[0]
         di = np.nonzero(idx >= self.n_rows)[0]
-        c0 = jnp.zeros((idx.size,) + ct.c0.shape[1:], ct.c0.dtype)
-        c1 = jnp.zeros((idx.size,) + ct.c1.shape[1:], ct.c1.dtype)
+        c0 = jnp.zeros((idx.size,) + ct.c0.shape[1:], jnp.int64)
+        c1 = jnp.zeros((idx.size,) + ct.c1.shape[1:], jnp.int64)
         c0 = c0.at[bi].set(ct.c0[idx[bi]])
         c1 = c1.at[bi].set(ct.c1[idx[bi]])
         c0 = c0.at[di].set(dct.c0[idx[di] - self.n_rows])
@@ -455,7 +496,7 @@ class Table:
         if include_padding and self.delta is not None:
             raise ValueError("include_padding only applies to an "
                              "uncompacted-delta-free table")
-        vals = np.asarray(E.decrypt(ks, self.columns[name]))
+        vals = np.asarray(E.decrypt(ks, widen(self.columns[name])))
         if include_padding:
             return vals
         vals = vals[:self.n_rows]
@@ -476,9 +517,9 @@ def append_rows(ks: KeySet, base: "Table", new: "Table") -> "Table":
     """Ciphertext-level append: `base`'s valid rows + `new`'s valid
     rows, re-padded to the next power of two with fresh encryptions of
     0.  No row is re-encrypted — existing ciphertexts are sliced and
-    concatenated (the same trick as `ShardedTable.from_table`).  Used to
-    grow a delta run and to fold a delta back into the base at
-    compaction."""
+    concatenated (the same trick as `ShardedTable.from_table`), int32
+    parts into an int32 block.  Used to grow a delta run and to fold a
+    delta back into the base at compaction."""
     if set(base.columns) != set(new.columns):
         raise ValueError("column mismatch between runs")
     n_total = base.n_rows + new.n_rows

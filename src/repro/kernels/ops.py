@@ -66,9 +66,9 @@ def device_lane_budget(params) -> int:
     launch's temporaries (`eval_lane_bytes`) fit 1/EVAL_MEMORY_SHARE of
     the device's memory where the backend reports it (a TPU does; the
     CPU backend does not, and keeps the constant).  Outside this share:
-    the table itself, and the 32-bit plane of an int64 column block a
-    TPU's scan-tile slice holds for a moment (`db.executor.scan_tile`,
-    half the block's bytes, whatever the lane count)."""
+    the table itself, stored as int32 (`db.table.store`), whose scan
+    tiles (`db.executor.scan_tile`) are cut in place and widened to
+    int64 inside the eval program, within the lanes counted here."""
     stats = jax.local_devices()[0].memory_stats() or {}
     limit = stats.get("bytes_limit")
     if not limit:
@@ -278,6 +278,9 @@ def _shard_eval_fn(ks: KeySet, mesh, axis_name: str, use_kernel: bool,
     from repro.core import compare as C
 
     def local_eval(c00, c01, b0, b1, *sel_arg):
+        # stored blocks are int32 (`db.table.store`): widen inside the
+        # program, where the conversion is as large as the local tile
+        c00, c01, b0, b1 = (x.astype(jnp.int64) for x in (c00, c01, b0, b1))
         if sel_arg:
             c00 = jnp.take(c00, sel_arg[0], axis=1)
             c01 = jnp.take(c01, sel_arg[0], axis=1)

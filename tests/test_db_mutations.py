@@ -163,6 +163,88 @@ def test_base_and_delta_ingest_agree_on_column_keys(bfv_engine_ks):
 
 
 # ---------------------------------------------------------------------------
+# storage: int32 column blocks, int64 for every reader but the scan
+# ---------------------------------------------------------------------------
+
+def _assert_stored_int32(t):
+    blocks = list(t.columns.values())
+    if t.delta is not None:
+        blocks += list(t.delta.columns.values())
+    for ct in blocks:
+        assert ct.c0.dtype == ct.c1.dtype == jnp.int32
+
+
+def _int64_bytes(t):
+    """What `t`'s blocks would take as int64."""
+    total = sum(2 * ct.c0.size * 8 for ct in t.columns.values())
+    return total + (0 if t.delta is None else _int64_bytes(t.delta))
+
+
+def test_blocks_stored_int32_and_read_as_int64(scheme_ks):
+    ks = scheme_ks
+    base = np.array([12, 3, 40, 7, 25, 18], np.int64)
+    t = Table.from_arrays(ks, "t", {"v": _vals(ks, base),
+                                    "w": _vals(ks, base + 1)},
+                          jax.random.PRNGKey(40))
+    _assert_stored_int32(t)
+    assert 2 * t.ciphertext_bytes() == _int64_bytes(t)
+    # a fresh delta run, then a grown one (`append_rows`), then an update
+    t.insert(ks, {"v": _vals(ks, [33, 9]), "w": _vals(ks, [34, 10])},
+             jax.random.PRNGKey(41))
+    _assert_stored_int32(t)
+    t.insert(ks, {"v": _vals(ks, [50, 1, 29]), "w": _vals(ks, [51, 2, 30])},
+             jax.random.PRNGKey(42))
+    _assert_stored_int32(t)
+    t.update(ks, [1], {"v": _vals(ks, [44]), "w": _vals(ks, [45])},
+             jax.random.PRNGKey(43))
+    _assert_stored_int32(t)
+    assert 2 * t.ciphertext_bytes() == _int64_bytes(t)
+    allv = np.array([12, 3, 40, 7, 25, 18, 33, 9, 50, 1, 29, 44], np.int64)
+    ids = np.arange(t.n_total)
+
+    def check_readers():
+        for c, off in (("v", 0), ("w", 1)):
+            want = _vals(ks, allv + off)
+            got = t.gather(c, ids)
+            assert got.c0.dtype == got.c1.dtype == jnp.int64
+            assert _close(ks, E.decrypt(ks, got), want)
+            col = t.column(c)
+            assert col.c0.dtype == col.c1.dtype == jnp.int64
+            assert _close(ks, E.decrypt(ks, col)[:t.n_rows],
+                          want[:t.n_rows])
+            scan = t.scan_column(c)
+            assert scan.c0.dtype == scan.c1.dtype == jnp.int64
+            slots = t.slot_global_ids
+            assert _close(ks, np.asarray(E.decrypt(ks, scan))[slots >= 0],
+                          want[slots[slots >= 0]])
+            assert _close(ks, t.decrypt_column(ks, c), want)
+
+    def check_queries(seed):
+        # ring arithmetic on stored rows: the top-k's compare network
+        # and the projection's decrypt answer exactly
+        live = t.alive
+        q = P.Query(where=_range(ks, 5, 45, seed), top_k=P.TopK("v", 3),
+                    select=("v", "w"))
+        res = db.execute(ks, t, q)
+        match = live & (allv >= 5) & (allv <= 45)
+        want = sorted(allv[match].tolist(), reverse=True)[:3]
+        assert allv[res.row_ids].tolist() == want
+        for c, off in (("v", 0), ("w", 1)):
+            assert res.columns[c].c0.dtype == jnp.int64
+            assert _close(ks, E.decrypt(ks, res.columns[c]),
+                          _vals(ks, np.asarray(want) + off))
+
+    check_readers()
+    check_queries(90)
+    db.compact(ks, t)
+    assert not t.has_delta
+    _assert_stored_int32(t)
+    assert 2 * t.ciphertext_bytes() == _int64_bytes(t)
+    check_readers()
+    check_queries(92)
+
+
+# ---------------------------------------------------------------------------
 # union reads: base ∪ delta scans, index probes, tombstones
 # ---------------------------------------------------------------------------
 

@@ -14,6 +14,8 @@ DEDUPED stack (U unique columns, not A atom copies), `eval.lanes` must
 still sum to exactly `scan_compares` across tiles, and `eval.tiles`
 must count the launches the budget implies.
 """
+import copy
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -23,6 +25,7 @@ from repro import db, obs
 from repro.core import encrypt as E
 from repro.db import executor as X
 from repro.db import plan as P
+from repro.db.table import concat_ct_rows, widen
 from repro.kernels import ops as KO
 
 GRID = 0.25          # ckks value lattice (>> test-ckks tolerance ~0.016)
@@ -133,6 +136,46 @@ def test_fused_eval_tiled_identical_across_schemes(scheme_ks):
     np.testing.assert_array_equal(res.mask, want)
 
 
+def _held_as_int64(table):
+    """The same ciphertexts held as int64 blocks (the scan's input before
+    column blocks were stored as int32 residues)."""
+    wide = copy.copy(table)
+    wide.columns = {c: widen(ct) for c, ct in table.columns.items()}
+    if table.delta is not None:
+        wide.delta = _held_as_int64(table.delta)
+    return wide
+
+
+def _check_int32_blocks_identical_to_int64(ks, with_delta):
+    table = _table(ks, BASE_INTS, name=f"int32_{with_delta}")
+    if with_delta:
+        table.insert(ks, {"v": _vals(ks, DELTA_INTS)}, jax.random.PRNGKey(9))
+        assert table.scan_width == 20      # 16-pad base + 4-pad delta
+    wide = _held_as_int64(table)
+    for blk, wblk in zip(table.scan_parts("v"), wide.scan_parts("v")):
+        assert blk.c0.dtype == blk.c1.dtype == jnp.int32
+        assert wblk.c0.dtype == wblk.c1.dtype == jnp.int64
+    q = db.And(_range_query(ks, 3, 8, 100), _range_query(ks, 2, 11, 200))
+    atoms = _scan_atoms(q)                 # A=4
+    # one tile; T=4; T=8, whose tiles leave the 4-row delta block ragged
+    for budget in (1 << 20, 16, 32):
+        np.testing.assert_array_equal(
+            X.fused_eval(ks, table, atoms, lane_budget=budget),
+            X.fused_eval(ks, wide, atoms, lane_budget=budget))
+
+
+@pytest.mark.parametrize("with_delta", [False, True],
+                         ids=["base", "base_delta"])
+def test_fused_eval_int32_blocks_identical_to_int64(scheme_ks, with_delta):
+    _check_int32_blocks_identical_to_int64(scheme_ks, with_delta)
+
+
+def test_fused_eval_int32_blocks_identical_to_int64_paper_mode(paper_keys):
+    # paper mode factors the eval per unique column: the stored tile
+    # meets `scale` and the key before any int64 operand could widen it
+    _check_int32_blocks_identical_to_int64(paper_keys, with_delta=True)
+
+
 def test_fused_eval_kernel_engine_tiled_identical(bfv_engine_ks):
     ks = bfv_engine_ks
     table = _table(ks, BASE_INTS)
@@ -189,13 +232,16 @@ def test_dedup_bytes_and_lane_accounting(bfv_engine_ks):
     q = db.And(_range_query(ks, 3, 8, 100), _range_query(ks, 2, 11, 200))
     atoms = _scan_atoms(q)                 # A=4 atoms, U=1 unique column
     W = table.scan_width
-    uniq, sel = X.dedup_atom_columns(table, atoms, table.scan_column)
+    # the stack as stored (int32 blocks), which is what the scan reads
+    uniq, sel = X.dedup_atom_columns(
+        table, atoms, lambda c: concat_ct_rows(*table.scan_parts(c)))
     assert uniq.c0.shape[0] == 1 and sel.tolist() == [0, 0, 0, 0]
+    assert uniq.c0.dtype == jnp.int32
     bounds = X.stack_atom_bounds(atoms)
     with obs.tracing():
         vals = X.fused_eval(ks, table, atoms)
-        # bytes moved are the UNIQUE stack + bounds (c0 and c1), not A
-        # full column copies — the dedup invariant in numbers
+        # bytes moved are the UNIQUE stored stack + bounds (c0 and c1),
+        # not A full column copies — the dedup invariant in numbers
         assert obs.REGISTRY.value("bytes.moved") == \
             2 * (uniq.c0.nbytes + bounds.c0.nbytes)
         assert obs.REGISTRY.value("eval.lanes") == len(atoms) * W

@@ -23,6 +23,7 @@ from repro.core import ring as R
 from repro.core.keys import KeySet
 from repro.core.params import make_params
 from repro.db import executor as X
+from repro.db import table as T
 from repro.db.table import INGEST_CHUNK_ROWS
 from repro.kernels import ops as KO
 
@@ -87,18 +88,19 @@ def test_fused_scan_tile_compiles_within_its_memory_share(paper_ks,
                                                           one_chip):
     """One `fused_eval` tile over the full hg38 column (65,536 padded
     rows), two atoms, at the lane tile a v5e derives: the slice that
-    cuts the tile from the column (`executor.scan_tile`), which holds
-    one 32-bit plane of the int64 column while splitting it into 32-bit
-    words, then the eval program, which takes only the tile."""
+    cuts the tile from the stored int32 column (`executor.scan_tile`),
+    which holds at most one tile (an int64 column would be split into
+    32-bit planes whole), then the eval program, which takes only the
+    int32 tile and widens it."""
     params = paper_ks.params
     K, n = params.num_towers, params.n
     A, W = 2, 65536
     t = KO.lane_tile(W, A, None, default=_v5e_lane_budget(params))
     cut = jax.jit(X.scan_tile, static_argnums=(2,)).lower(
-        _spec(one_chip, W, K, n), _spec(one_chip, dtype=jnp.int32),
-        t).compile()
-    assert cut.memory_analysis().temp_size_in_bytes <= W * K * n * 4
-    tile = _spec(one_chip, 1, t, K, n)
+        _spec(one_chip, W, K, n, dtype=jnp.int32),
+        _spec(one_chip, dtype=jnp.int32), t).compile()
+    assert cut.memory_analysis().temp_size_in_bytes <= t * K * n * 4
+    tile = _spec(one_chip, 1, t, K, n, dtype=jnp.int32)
     compiled = X.jitted_dedup_eval(paper_ks).lower(
         tile, tile, _spec(one_chip, A), _spec(one_chip, A, 1, K, n),
         _spec(one_chip, A, 1, K, n)).compile()
@@ -150,6 +152,24 @@ def test_ingest_encrypt_chunk_compiles(paper_ks, one_chip):
         _spec(one_chip, 2, dtype=jnp.uint32)).compile()
     assert (compiled.memory_analysis().temp_size_in_bytes
             < V5E_HBM_BYTES // 4)
+
+
+def test_ingest_write_compiles_in_place(one_chip):
+    """`table.ingest_write` narrows one encrypted int64 chunk into the
+    donated int32 column buffers of the full hg38 column (65,536 padded
+    rows): it holds at most the narrowed chunk, never an int64 column,
+    and writes its output into the donated buffers."""
+    params = make_params("paper-bfv", mode="gadget")
+    K, n = params.num_towers, params.n
+    W, chunk = 65536, INGEST_CHUNK_ROWS
+    col = _spec(one_chip, W, K, n, dtype=jnp.int32)
+    part = _spec(one_chip, chunk, K, n)
+    compiled = T.ingest_write.lower(
+        col, col, E.Ciphertext(part, part),
+        _spec(one_chip, dtype=jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes <= chunk * K * n * 4
+    assert mem.alias_size_in_bytes == 2 * W * K * n * 4
 
 
 def test_sharded_scan_compiles_on_four_chips(paper_ks, topo, one_chip):
