@@ -1,6 +1,7 @@
 """Builder `one_chip`: every table of the mix on one chip, each with the
 `SortedIndex`es its configuration entry asks for, behind one `ServeLoop`
-of `QueryServer`s.
+of `QueryServer`s, with the mix's `batch` and, where the mix states it,
+its `tenant_queue_cap` (else the loop's default admission policy).
 
 Phases: `keygen`; `ingest.<table>` and `index_build.<table>` for every
 table the mix loads, in the configuration's order; the shared
@@ -30,7 +31,7 @@ def build(cfg, mix, data, schedule, seed, record, log, peak):
 
     from repro.core.keys import keygen
     from repro.core.params import make_params
-    from repro.db.serve_loop import ServeLoop
+    from repro.db.serve_loop import AdmissionPolicy, ServeLoop
 
     params = make_params(cfg["profile"], mode=cfg["cek_mode"])
     with phase("keygen", record, log, peak):
@@ -38,7 +39,10 @@ def build(cfg, mix, data, schedule, seed, record, log, peak):
         jax.block_until_ready(ks.cek_rev)
 
     batch = int(mix["batch"])
-    loop = ServeLoop(batch=batch, clock=time.perf_counter)
+    policy = AdmissionPolicy()
+    if "tenant_queue_cap" in mix:
+        policy = AdmissionPolicy(tenant_queue_cap=int(mix["tenant_queue_cap"]))
+    loop = ServeLoop(batch=batch, policy=policy, clock=time.perf_counter)
     for i, t in enumerate(cfg["tables"]):
         if t["name"] not in mix["tables"]:
             continue

@@ -55,6 +55,7 @@ class Context:
     trace: Optional[dict] = None
     notes: List[str] = dataclasses.field(default_factory=list)
     platform: str = "tpu"
+    chips: int = 1
 
     @property
     def sizes(self) -> Dict[str, int]:
@@ -119,7 +120,7 @@ def run(cell: str, seed: int, seconds: float, traced: bool, *,
     log(f"gc: {len(gc.get_objects())} objects tracked at the window's "
         f"opening, thresholds {gc.get_threshold()}")
     ctx = Context(cell, traced, window, setup_s, phases, cfg, mix, peaks,
-                  platform=dev["platform"])
+                  platform=dev["platform"], chips=chips)
     programs0 = meter.snapshot()[0]
     if traced:
         _traced_window(window, seconds, ctx)
@@ -151,6 +152,8 @@ def run(cell: str, seed: int, seconds: float, traced: bool, *,
     log(f"generator lateness over {len(lat)} sends: p50 "
         f"{_pct(lat, 50) * 1e3:.3f} ms, p99 {_pct(lat, 99) * 1e3:.3f} ms, "
         f"max {lat[-1] * 1e3 if lat else 0:.3f} ms")
+    for line in _backlog_lines(recs, window.t0):
+        log(line)
     metrics = {}
     for m in spec.metrics_for(bench, cell, traced):
         value = spec.load_module("metrics", m["name"]).read(ctx)
@@ -176,12 +179,39 @@ def run(cell: str, seed: int, seconds: float, traced: bool, *,
            "device": dev_out}
     if traced and ctx.trace is not None:
         dev_out["busy_s"] = ctx.trace["busy_s"]
+        dev_out["busy_s_by_chip"] = ctx.trace["busy_s_by_chip"]
         dev_out["window_s"] = ctx.trace["window_s"]
         out["breakdown"] = {"device_ops": ctx.trace["top_programs"],
                             "idle_gaps": ctx.trace["idle_by_host"]}
     out["checks"] = {k: {"value": v, "limit": 0} for k, v in checks.items()}
     for k, v in checks.items():
         log(f"check {k}: {v} (limit 0)")
+    return out
+
+
+def _backlog_lines(recs: List[dict], t0: float) -> List[str]:
+    """The most requests in flight at once (sent, not yet answered), and
+    the requests that did not end OK by status and reason, with when
+    they were due: a backlog that reached the loop's admission caps
+    shows here."""
+    events = sorted([(r["sent"], 1) for r in recs]
+                    + [(r["done"], -1) for r in recs
+                       if r["done"] is not None])
+    most, at, depth = 0, 0.0, 0
+    for t, step in events:
+        depth += step
+        if depth > most:
+            most, at = depth, t
+    out = [f"in flight: at most {most} requests at once, at "
+           f"+{at - t0:.3f}s"]
+    bad: Dict[tuple, List[float]] = {}
+    for r in recs:
+        if r["status"] != reference.OK:
+            reason = (r["error"] or "").split(" (")[0]
+            bad.setdefault((r["status"], reason), []).append(r["due"] - t0)
+    for (status, reason), dues in sorted(bad.items()):
+        out.append(f"not OK: {len(dues)} {status} ({reason or '-'}), due "
+                   f"+{min(dues):.3f}s to +{max(dues):.3f}s")
     return out
 
 
@@ -220,23 +250,22 @@ def _histogram(registry, flat: str) -> List[float]:
 
 
 def _read_trace(ctx: Context) -> None:
-    """Reduce the traced window's profile (after the drain, so reading
-    it delays no answer)."""
+    """Reduce the traced window's profile, every chip of the cell
+    (after the drain, so reading it delays no answer)."""
     from harness import trace as T
     try:
-        tr = T.read_xplane(T.find_xplane(str(TRACE_DIR)), ctx.platform)
+        tr = T.read_xplane(T.find_xplane(str(TRACE_DIR)), ctx.platform,
+                           ctx.chips)
     finally:
         shutil.rmtree(TRACE_DIR, ignore_errors=True)
     span = T.window_of(tr["host"])
     ctx.notes.append("trace planes: " + "; ".join(
         f"{p} [{', '.join(ls[:6])}]" for p, ls in tr["planes"].items()))
-    if span is None or not tr["device"]:
+    if span is None or not any(tr["device"]):
         raise RuntimeError("the trace holds no window annotation or no "
                            "device op: " + ctx.notes[-1])
-    lo, hi = span
-    tr["busy_s"] = T.busy_seconds(tr["device"], lo, hi)
-    tr["window_s"] = hi - lo
-    tr["top_programs"] = T.top_programs(tr["modules"] or tr["device"],
-                                        lo, hi)
-    tr["idle_by_host"] = T.idle_by_host(tr["device"], tr["host"], lo, hi)
+    tr.update(T.reduce_window(tr, *span))
+    ctx.notes.append("busy by chip (s): " + ", ".join(
+        f"{name} {b:.6f}" for name, b in zip(
+            tr["read"], tr["busy_s_by_chip"])))
     ctx.trace = tr

@@ -16,10 +16,12 @@ launch evaluates A atoms against t rows of U distinct columns:
          chip's int8 peak counts them
 
 Least time is the larger of bytes over the memory bandwidth and ops over
-the int8 peak.  Stored int64 reads twice the counted row bytes, so a
-share of this least time cannot pass 100%.  This count holds for the
-full-noise gadget key; the zero-noise paper key's eval reads less and
-needs a count of its own.
+the int8 peak.  Both counts are of what any implementation has to read
+and do: every residue read once, at the 4 B in which the column stores
+it, and every product counted at the chip's cheapest rate.  No launch
+takes less, so a share of this least time cannot pass 100%.  This count
+holds for the full-noise gadget key; the zero-noise paper key's eval
+reads less and needs a count of its own.
 """
 from __future__ import annotations
 

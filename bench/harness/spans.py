@@ -24,7 +24,7 @@ import collections
 import heapq
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from harness.trace import union
+from harness.trace import idle_stretches, mean_by_key
 
 ProgramEvent = Tuple[str, float, float, int, int]
 NONE = "(none)"
@@ -56,18 +56,6 @@ def depths(program: Sequence[ProgramEvent]) -> Dict[int, int]:
             d += 1
             out[s] = d
     return out
-
-
-def idle_stretches(device, lo: float, hi: float) -> List[Tuple[float, float]]:
-    """The stretches of [lo, hi] in which no op ran on the device."""
-    idle, t = [], lo
-    for s, e in union([(s, e) for _, s, e in device], lo, hi):
-        if s > t:
-            idle.append((t, s))
-        t = max(t, e)
-    if t < hi:
-        idle.append((t, hi))
-    return idle
 
 
 def idle_by_sid(device, program: Sequence[ProgramEvent], lo: float,
@@ -165,7 +153,8 @@ def _stray(program: Sequence[ProgramEvent], host) -> Optional[float]:
 
 
 def split(ctx) -> Optional[dict]:
-    """The traced window's idle time by innermost program span, computed
+    """The traced window's idle time by innermost program span, each
+    chip's split averaged over the chips (as `busy_s` is), computed
     once per run and logged to `ctx.notes`: `by_name` (name -> s),
     `window_s`, `idle_s`, and the idle seconds under `bench.pump` and
     under a span below `serve.pump`.  None without a trace or spans."""
@@ -176,8 +165,8 @@ def split(ctx) -> Optional[dict]:
     lo, hi = ctx.trace_window
     w = ctx.window
     program = from_tracer(ctx.spans, w.t0, w.end, lo, hi)
-    dev = ctx.trace["device"]
-    by_sid = idle_by_sid(dev, program, lo, hi)
+    by_sid = mean_by_key([idle_by_sid(dev, program, lo, hi)
+                          for dev in ctx.trace["device"]])
     below = _below(program, PUMP)
     out = {"by_name": _by_name(program, by_sid), "window_s": hi - lo,
            "idle_s": sum(by_sid.values()),

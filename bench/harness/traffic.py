@@ -4,6 +4,9 @@ A mix (`bench/traffic/<name>.json`) is data.  It states
 
   tables      every table the run loads (the configuration's names)
   batch       the serving loop's batch cap
+  tenant_queue_cap
+              (optional) the most requests of one tenant the loop holds
+              queued before it refuses more; absent: the loop's default
   streams     one or more request streams, offered side by side:
 
     loop        its arrival process, `bench/traffic/<loop>.py` (`poisson`:

@@ -7,8 +7,10 @@ Launches and their shapes come from the `executor.eval_tile` spans
 (`rows`) and their parent `executor.fused_eval` spans (`atoms`); the
 mixes that report this metric scan one column, so each launch reads
 one column's tile.  Device time is the summed duration of the launches
-of the programs named in PROGRAMS, as the trace names them: the jitted
-eval of `repro.db.executor.jitted_dedup_eval`.
+of the programs named in PROGRAMS, as the trace names them (the jitted
+eval of `repro.db.executor.jitted_dedup_eval`), summed over the chips
+as the launches' work is, so the share cannot pass 100% on more chips
+either.
 """
 from harness import roofline
 from harness.trace import kernel_seconds
@@ -34,8 +36,8 @@ def read(ctx):
         least += t["seconds"]
         t_bytes += t["bytes_s"]
         t_ops += t["ops_s"]
-    kernel = kernel_seconds(ctx.trace["modules"], PROGRAMS,
-                            *ctx.trace_window)
+    kernel = sum(kernel_seconds(modules, PROGRAMS, *ctx.trace_window)
+                 for modules in ctx.trace["modules"])
     if not least or not kernel:
         return None
     ctx.notes.append(

@@ -9,8 +9,9 @@ Earlier lines (standard error) carry the set-up split into phases, the
 tables, generator lateness, programs compiled in the window and the
 numbers compared with their limits; the last line of standard output is
 the result: `correct`, `attempted`, `failed`, `metrics`, `device`
-(`busy_s` and `window_s` when traced), `breakdown` when traced, and
-`checks`, each compared number beside its limit.
+(`busy_s`, the mean over the cell's chips, `busy_s_by_chip` and
+`window_s` when traced), `breakdown` when traced, and `checks`, each
+compared number beside its limit.
 
 Exits non-zero, printing no result, when JAX finds no TPU or fewer
 chips than the cell asks for, or when the program is not in the

@@ -6,12 +6,10 @@ import time
 
 import pytest
 
-from harness import cell, spec
+import tiny
 from harness import spans as S
 from harness import trace as T
-from tiny import tiny_config, tiny_mix
 
-BENCH = spec.load_benchmark()
 SEED = 2**31 + 4321
 
 
@@ -86,17 +84,12 @@ CELLS = {"hg38-point": (1.0, ["probe_idle_pct.lookup"]),
 
 
 @pytest.mark.parametrize("name", list(CELLS))
-def test_traced_run_splits_idle_by_span(name, tmp_path, monkeypatch):
+def test_traced_run_splits_idle_by_span(name, tmp_path):
     # a trace directory of its own: another test file's traced run may
     # run beside this one, in another process
-    monkeypatch.setattr(cell, "TRACE_DIR", tmp_path / "trace")
     seconds, want = CELLS[name]
-    wl = spec.workload(BENCH, name)
     lines = []
-    out = cell.run(name, SEED, seconds, True, require_tpu=False,
-                   config_override=tiny_config(spec.config(
-                       BENCH, wl["config"])),
-                   mix_override=tiny_mix(spec.traffic(wl["traffic"])),
+    out = tiny.run(name, SEED, seconds, True, trace_dir=tmp_path / "trace",
                    log=lines.append)
     assert out["correct"], out["checks"]
     m = out["metrics"]
