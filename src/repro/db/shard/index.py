@@ -134,38 +134,54 @@ class ShardedIndex:
         lo = np.zeros((S, B), np.int64)
         hi = np.broadcast_to(self.counts[:, None], (S, B)).copy()
         home = values.c0.devices().pop()
+        # shards whose probed rows leave their chip for the home chip
+        away = [bool(ix.n_rows) and ix.sorted_ct.c0.devices() != {home}
+                for ix in self.shards]
 
         def gather(probe):
             # each shard's probed rows, gathered where the shard lives,
             # then brought beside the trapdoors: [S, B, ...]
-            out0, out1 = [], []
+            out0, out1, moved = [], [], 0
             for s, ix in enumerate(self.shards):
                 c0, c1 = ix.sorted_ct
                 if ix.n_rows:
                     c0, c1 = c0[probe[s]], c1[probe[s]]
                 else:          # an empty shard's lanes are all dead
                     c0 = c1 = jnp.zeros((B,) + c0.shape[1:], c0.dtype)
+                if away[s]:
+                    moved += c0.nbytes + c1.nbytes
                 out0.append(jax.device_put(c0, home))
                 out1.append(jax.device_put(c1, home))
+            obs.count("shard.index.bytes_moved", moved)
             return Ciphertext(jnp.stack(out0), jnp.stack(out1))
         lane_probes = np.zeros(B, np.int64)
         with obs.span("shard.index.search", column=self.column,
                       shards=S, lanes=B) as sp:
+            step = 0
             while np.any(lo < hi):
                 active = lo < hi
-                mid = (lo + hi) // 2
-                probe = np.where(active, mid, 0)
-                rows = gather(probe)
-                obs.jit_launch("shard.index.probe", rows.c0, values.c0)
-                obs.count("eval.launches")
-                obs.count("eval.lanes", S * B)
-                v = np.asarray(ev(rows, values))               # [S, B] raw
-                c = np.where(np.abs(v) < taus[None, :], 0, np.sign(v))
-                lane_probes += active.sum(axis=0)
-                go_left = np.where(strict[None, :], c > 0, c >= 0)
-                hi = np.where(active & go_left, mid, hi)
-                lo = np.where(active & ~go_left, mid + 1, lo)
-            sp.set(probes=int(lane_probes.sum()))
+                # step self time: the per-shard gathers, the moves to
+                # the home chip and the eval dispatch; decode: the
+                # device round trip and the host's bounds update
+                with obs.span("shard.index.step", step=step,
+                              lanes=int(active.sum())):
+                    mid = (lo + hi) // 2
+                    probe = np.where(active, mid, 0)
+                    rows = gather(probe)
+                    obs.jit_launch("shard.index.probe", rows.c0, values.c0)
+                    obs.count("eval.launches")
+                    obs.count("eval.lanes", S * B)
+                    v = ev(rows, values)
+                    with obs.span("shard.index.decode"):
+                        v = np.asarray(v)                      # [S, B] raw
+                        c = np.where(np.abs(v) < taus[None, :], 0,
+                                     np.sign(v))
+                        lane_probes += active.sum(axis=0)
+                        go_left = np.where(strict[None, :], c > 0, c >= 0)
+                        hi = np.where(active & go_left, mid, hi)
+                        lo = np.where(active & ~go_left, mid + 1, lo)
+                step += 1
+            sp.set(probes=int(lane_probes.sum()), steps=step)
         obs.count("index.probes", int(lane_probes.sum()))
         self.search_compares += int(lane_probes.sum())
         self.last_probe_counts = lane_probes

@@ -144,6 +144,24 @@ def test_index_probe_eval_compiles(paper_ks, one_chip):
                      _spec(one_chip, B, K, n))).compile()
 
 
+def test_fanout_probe_compiles(paper_ks, one_chip):
+    """One step of the fan-out search over the whole hg38 column in 4
+    shards: 16 probed rows out of one shard's int64 sorted copy (8,606
+    of the column's 34,423 rows), then the eval of all 4 shards' rows on
+    the home chip against the batch's 16 trapdoor lanes."""
+    K, n = paper_ks.params.num_towers, paper_ks.params.n
+    S, B, rows = 4, 16, 8606
+    gather = jax.jit(lambda c, p: c[p]).lower(
+        _spec(one_chip, rows, K, n), _spec(one_chip, B)).compile()
+    assert (gather.memory_analysis().temp_size_in_bytes
+            < V5E_HBM_BYTES // 4)
+    X.jitted_eval(paper_ks).lower(
+        E.Ciphertext(_spec(one_chip, S, B, K, n),
+                     _spec(one_chip, S, B, K, n)),
+        E.Ciphertext(_spec(one_chip, B, K, n),
+                     _spec(one_chip, B, K, n))).compile()
+
+
 def test_ingest_encrypt_chunk_compiles(paper_ks, one_chip):
     """One `Table.from_arrays` chunk: INGEST_CHUNK_ROWS rows encrypted."""
     fn = jax.jit(lambda m, key: E.encrypt(paper_ks, m, key))
