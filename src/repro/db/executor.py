@@ -104,9 +104,25 @@ def _jitted(ks: KeySet, name: str, fn, **jit_kw):
 
 def jitted_eval(ks: KeySet):
     """Jitted raw eval values (no threshold) closed over the keyset —
-    the fused scan and the index search both decode from this, applying
-    their own per-atom / per-lane τ on the host."""
+    the fan-out index search and the join passes decode from this,
+    applying their own per-lane τ on the host."""
     return _jitted(ks, "eval", lambda a, b: C.eval_value(ks, a, b))
+
+
+def jitted_probe(ks: KeySet):
+    """Jitted binary-search probe step of a `SortedIndex`: gathers the
+    [B] probed rows out of the stored (int32) sorted copy, widens them
+    and returns their raw eval values against the B trapdoor lanes —
+    one program a step, specialised per (copy rows, lanes).
+
+    The copy is an operand, never closed over (a closure would bake a
+    copy-sized constant into the program).  An int32 copy is read in
+    place: on a TPU a program that takes an int64 copy splits the whole
+    copy into 32-bit planes, whatever rows it reads."""
+    def index_probe(c0, c1, probe, b0, b1):
+        rows = widen(Ciphertext(c0[probe], c1[probe]))
+        return C.eval_value(ks, rows, Ciphertext(b0, b1))
+    return _jitted(ks, "index_probe", index_probe)
 
 
 def jitted_dedup_eval(ks: KeySet, axis: int = 0):

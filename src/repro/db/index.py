@@ -21,6 +21,18 @@ boundaries of [v-ε, v+ε] directly: lower lane "first row with
 col > v - ε", upper lane "first row with col > v + ε", both expressed
 through the widened τ_ε on the SAME trapdoor ciphertext — the client
 sends one encrypted v, never ε-shifted plaintexts.
+
+STORAGE.  The sorted copy is kept in the storage form of `db.table`
+(`store`: int32 residues, exact as every residue lies in [0, q) with
+q < 2^31), whatever built it — the constructor converts, so the base
+build, the delta-run indexes, compaction's merged runs and the
+per-shard runs of `ShardedIndex` all store int32.  A probe step is ONE
+jitted program (`executor.jitted_probe`, `index_probe`) that takes the
+copy as an operand, gathers the B probed rows, widens them to int64
+and evaluates them: on a TPU an int64 copy is a pair of 32-bit planes,
+and a program that takes it splits the whole copy, whatever few rows
+it reads; an int32 copy is read in place.  Readers that compute on the
+copy (`sorted_run`, the merge networks) get it widened.
 """
 from __future__ import annotations
 
@@ -35,7 +47,7 @@ from repro.core import compare as C
 from repro.core.ckks import eps_to_tau
 from repro.core.encrypt import Ciphertext
 from repro.core.keys import KeySet
-from repro.db.table import Table, rows_to_mask
+from repro.db.table import Table, rows_to_mask, store, widen
 
 
 def _stack_cts(cts) -> Ciphertext:
@@ -54,18 +66,19 @@ def eps_lane_taus(ks: KeySet, eps: Optional[float]) -> Optional[np.ndarray]:
 
 
 class SortedIndex:
-    """Sorted ciphertext column + permutation, with encrypted binary search."""
+    """Sorted ciphertext column (stored int32) + permutation, with
+    encrypted binary search."""
 
     def __init__(self, column: str, sorted_ct: Ciphertext, perm: np.ndarray,
                  *, build_compares: int = 0):
         self.column = column
-        self.sorted_ct = sorted_ct
+        self.sorted_ct = store(sorted_ct)
         self.perm = np.asarray(perm)
         self.n_rows = int(self.perm.shape[0])
         self.build_compares = build_compares
         self.search_compares = 0               # cumulative probe count
         self.last_probe_counts = np.zeros(0, np.int64)  # per-lane, last call
-        self._cmp: Optional[Callable] = None   # jitted raw probe Eval, lazy
+        self._probe: Optional[Callable] = None  # jitted probe step, lazy
 
     # -- construction ------------------------------------------------------
 
@@ -81,6 +94,7 @@ class SortedIndex:
             from repro.db.executor import jitted_comparator
             comparator = jitted_comparator(ks)
         sorted_ct, perm = C.encrypted_sort(ks, col, comparator)
+        del col
         return cls(column, sorted_ct, np.asarray(perm),
                    build_compares=C.bitonic_compare_count(table.n_rows))
 
@@ -88,20 +102,22 @@ class SortedIndex:
         """The index as an ascending (ciphertext run, row-id array) pair —
         the sort-merge join consumes this directly, so a join between two
         indexed columns pays ZERO extra sort compares (the build is
-        already amortized across lookups)."""
-        return self.sorted_ct, self.perm
+        already amortized across lookups).  The run is widened to the
+        int64 that the merge networks compute in."""
+        return widen(self.sorted_ct), self.perm
 
     # -- search ------------------------------------------------------------
 
-    def _eval(self, ks: KeySet) -> Callable:
-        """Jitted raw probe Eval (jit specializes per lane shape), shared
-        per KeySet so a fresh delta-run index reuses compiled probes.
-        The three-way decode happens host-side so each lane applies its
+    def _probe_step(self, ks: KeySet) -> Callable:
+        """Jitted probe step — gather, widen, raw Eval (jit specializes
+        per copy and lane shape) — shared per KeySet so a fresh
+        delta-run index of a size seen before reuses its program.  The
+        three-way decode happens host-side so each lane applies its
         own τ (profile default or ε-derived)."""
-        if self._cmp is None:
-            from repro.db.executor import jitted_eval
-            self._cmp = jitted_eval(ks)
-        return self._cmp
+        if self._probe is None:
+            from repro.db.executor import jitted_probe
+            self._probe = jitted_probe(ks)
+        return self._probe
 
     def _lane_taus(self, ks: KeySet, n_lanes: int,
                    taus: Optional[np.ndarray]) -> np.ndarray:
@@ -121,14 +137,16 @@ class SortedIndex:
         taus[i] (optional) is lane i's decode threshold: with a widened
         τ_ε, "col >= v" means "col > v - ε" and "col > v" means
         "col > v + ε" — the ε-aware boundary semantics the ε-band
-        predicates lower to.  Every iteration is ONE batched Eval over
-        the B probe lanes.
+        predicates lower to.  Every iteration is ONE program over the
+        B probe lanes: the gather out of the stored copy and the
+        batched Eval together.
         """
         strict = np.asarray(strict, bool)
         B = values.c0.shape[0]
         assert strict.shape == (B,)
         taus = self._lane_taus(ks, B, taus)
-        ev = self._eval(ks)
+        step_fn = self._probe_step(ks)
+        c0, c1 = self.sorted_ct
         lo = np.zeros(B, np.int64)
         hi = np.full(B, self.n_rows, np.int64)
         probes = np.zeros(B, np.int64)
@@ -137,18 +155,16 @@ class SortedIndex:
             step = 0
             while np.any(lo < hi):
                 active = lo < hi
-                # step self time: the eager gather + the eval dispatch;
-                # decode: the device round trip + the host's bounds update
+                # step self time: the probe program's dispatch; decode:
+                # the device round trip + the host's bounds update
                 with obs.span("index.step", step=step,
                               active=int(active.sum())):
                     mid = (lo + hi) // 2
                     probe = np.where(active, mid, 0)  # fixed shape; dead lanes
-                    rows = Ciphertext(self.sorted_ct.c0[probe],
-                                      self.sorted_ct.c1[probe])
-                    obs.jit_launch("index.probe", rows.c0, values.c0)
+                    obs.jit_launch("index.probe", c0, probe, values.c0)
                     obs.count("eval.launches")
                     obs.count("eval.lanes", B)
-                    v = ev(rows, values)
+                    v = step_fn(c0, c1, probe, values.c0, values.c1)
                     with obs.span("index.decode"):
                         v = np.asarray(v)                      # [B] raw
                         c = np.where(np.abs(v) < taus, 0,

@@ -12,7 +12,10 @@ so a range query over S shards still costs only ~log₂(max shard size)
 launches.  Boundary lanes then combine per shard into local row masks
 (the executor lifts them to the global mask).  Per-lane decode
 thresholds ride exactly as in `SortedIndex.search` — ε-band lanes work
-unchanged.
+unchanged.  Each shard's sorted copy is stored int32 (`SortedIndex`):
+a step gathers and widens its probed rows in one program where the
+shard lives (`db.table.take_rows`), reading the copy in place, then
+moves them to the home chip for the eval.
 """
 from __future__ import annotations
 
@@ -29,7 +32,7 @@ from repro.core.keys import KeySet
 from repro.db.index import SortedIndex, _stack_cts, eps_lane_taus
 from repro.db.shard import merge as M
 from repro.db.shard.table import ShardedTable
-from repro.db.table import rows_to_mask
+from repro.db.table import rows_to_mask, take_rows
 
 
 class ShardedIndex:
@@ -88,7 +91,8 @@ class ShardedIndex:
                 ks, cmp, ct.c0, ct.c1, ids, block=block))
             del ct
         shards = []
-        for c0, c1, gid, n in networks:
+        while networks:                  # each int64 network output freed
+            c0, c1, gid, n = networks.pop(0)     # once its run is stored
             gid = np.asarray(gid)
             keep = np.nonzero(gid >= 0)[0]
             # each shard rode a block-row network (the common padded
@@ -139,15 +143,16 @@ class ShardedIndex:
                 for ix in self.shards]
 
         def gather(probe):
-            # each shard's probed rows, gathered where the shard lives,
-            # then brought beside the trapdoors: [S, B, ...]
+            # each shard's probed rows, gathered out of its int32 copy
+            # (read in place) and widened where the shard lives, then
+            # brought beside the trapdoors: [S, B, ...]
             out0, out1, moved = [], [], 0
             for s, ix in enumerate(self.shards):
                 c0, c1 = ix.sorted_ct
                 if ix.n_rows:
-                    c0, c1 = c0[probe[s]], c1[probe[s]]
+                    c0, c1 = take_rows(ix.sorted_ct, probe[s])
                 else:          # an empty shard's lanes are all dead
-                    c0 = c1 = jnp.zeros((B,) + c0.shape[1:], c0.dtype)
+                    c0 = c1 = jnp.zeros((B,) + c0.shape[1:], jnp.int64)
                 if away[s]:
                     moved += c0.nbytes + c1.nbytes
                 out0.append(jax.device_put(c0, home))

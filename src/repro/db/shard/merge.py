@@ -174,7 +174,8 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
     never by value, exactly the core networks' tie-robust contract.
     Block s's pads encrypt under key `pad_salt + s`, so a caller padding
     shard s alone (`num_blocks=1, pad_salt=s`) gets the pads it would
-    get in the stack.
+    get in the stack.  The stack is int64, the networks' form: a stored
+    (int32) run, such as a `SortedIndex` copy, is widened here.
     """
     from repro.db.table import encrypt_constant, widen
     pad_key = jax.random.PRNGKey(0x5A4D)
@@ -184,6 +185,8 @@ def pad_shard_blocks(ks: KeySet, per_shard: list, *, block: int,
                     else (None, np.zeros(0, np.int64)))
         m = int(np.asarray(gids).shape[0])
         assert m <= block
+        if m:
+            ct = widen(ct)
         parts0 = [ct.c0] if m else []
         parts1 = [ct.c1] if m else []
         if m < block:

@@ -125,7 +125,7 @@ def widen(ct: Ciphertext) -> Ciphertext:
 
 
 @jax.jit
-def _take_rows(ct: Ciphertext, idx: jax.Array) -> Ciphertext:
+def take_rows(ct: Ciphertext, idx: jax.Array) -> Ciphertext:
     """Rows `idx` of a stored block, widened to int64: one program per
     shape, the gather and the conversion together."""
     return widen(Ciphertext(ct.c0[idx], ct.c1[idx]))
@@ -475,7 +475,7 @@ class Table:
         idx = np.asarray(rows, dtype=np.int64)
         ct = self.columns[name]
         if self.delta is None or idx.size == 0 or (idx < self.n_rows).all():
-            return _take_rows(ct, idx)
+            return take_rows(ct, idx)
         dct = self.delta.columns[name]
         bi = np.nonzero(idx < self.n_rows)[0]
         di = np.nonzero(idx >= self.n_rows)[0]

@@ -22,8 +22,8 @@ ROWS, SHARDS, LANES = 300, 4, 6
 
 def _search(use_mesh: bool) -> dict:
     """One traced fan-out search over a fresh 4-shard table: its spans,
-    counters, the eval calls it made and the bytes the non-home shards'
-    gathered rows hold."""
+    counters, the eval calls it made and the bytes a row of the shards'
+    stored copies holds."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -65,11 +65,12 @@ def _search(use_mesh: bool) -> dict:
     counters = {k: v for k, v in obs.REGISTRY.snapshot().items()
                 if isinstance(v, int)}
     away = sum(ix.sorted_ct.c0.devices() != {home} for ix in sidx.shards)
-    row = sidx.shards[0].sorted_ct.c0[0].nbytes * 2   # a lane's c0 + c1
+    stored = sidx.shards[0].sorted_ct.c0[0].nbytes * 2   # a lane's c0 + c1
     want = np.asarray([[np.sum(np.sort(ix_vals) < p) for p in probes]
                        for ix_vals in np.split(vals, st.offsets[1:-1])])
     return {"spans": spans, "counters": counters, "rounds": len(calls),
-            "away_shards": int(away), "row_bytes": int(row),
+            "away_shards": int(away), "stored_row_bytes": int(stored),
+            "row_words": ks.params.num_towers * ks.params.n,
             "devices": sorted({d.id for ix in sidx.shards
                                for d in ix.sorted_ct.c0.devices()}),
             "positions_exact": bool(np.array_equal(pos, want))}
@@ -117,7 +118,11 @@ def test_bytes_moved_counts_the_non_home_shards(child):
     out = child["mesh"]
     assert out["devices"] == list(range(SHARDS))
     assert out["away_shards"] == SHARDS - 1
-    want = out["rounds"] * (SHARDS - 1) * LANES * out["row_bytes"]
+    # a row is stored as int32 c0 and c1 (2·K·n·4 bytes) and moves
+    # widened, at twice that
+    assert out["stored_row_bytes"] == 2 * out["row_words"] * 4
+    row = 2 * out["stored_row_bytes"]
+    want = out["rounds"] * (SHARDS - 1) * LANES * row
     assert out["counters"]["shard.index.bytes_moved"] == want > 0
 
 

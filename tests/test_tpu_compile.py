@@ -133,28 +133,54 @@ def test_eval_lane_bytes_bounds_the_compiled_temporaries(paper_ks, one_chip,
             <= B * KO.eval_lane_bytes(params))
 
 
+def _probe_temp_bytes(ks, sharding, rows: int, lanes: int) -> int:
+    """Temp bytes of the fused probe step (`executor.jitted_probe`):
+    `lanes` rows gathered out of an s32 sorted copy of `rows` rows,
+    widened and evaluated against `lanes` trapdoor lanes."""
+    K, n = ks.params.num_towers, ks.params.n
+    copy = _spec(sharding, rows, K, n, dtype=jnp.int32)
+    lane = _spec(sharding, lanes, K, n)
+    compiled = X.jitted_probe(ks).lower(
+        copy, copy, _spec(sharding, lanes), lane, lane).compile()
+    return compiled.memory_analysis().temp_size_in_bytes
+
+
 def test_index_probe_eval_compiles(paper_ks, one_chip):
-    """The binary-search probe: a few gathered index rows against the
-    query's trapdoor lanes."""
-    K, n = paper_ks.params.num_towers, paper_ks.params.n
+    """The binary-search probe: a few index rows gathered out of the
+    stored s32 copy (hg38-point's 8,192 rows), widened and evaluated
+    against the query's trapdoor lanes, in one program."""
     B = 4
-    X.jitted_eval(paper_ks).lower(
-        E.Ciphertext(_spec(one_chip, B, K, n), _spec(one_chip, B, K, n)),
-        E.Ciphertext(_spec(one_chip, B, K, n),
-                     _spec(one_chip, B, K, n))).compile()
+    assert (_probe_temp_bytes(paper_ks, one_chip, 8192, B)
+            <= B * KO.eval_lane_bytes(paper_ks.params))
+
+
+def test_index_probe_temp_bytes_do_not_grow_with_the_copy(paper_ks,
+                                                          one_chip):
+    """The probe step at 16 lanes over s32 copies of 8,192 rows
+    (hg38-point) and 16,384 rows (ycsb-d-latest): the same temporaries
+    for both, within the lanes' eval bytes — nothing scales with the
+    copy (an s64 copy was split whole: 268,435,456 temp bytes at 8,192
+    rows)."""
+    B = 16
+    small = _probe_temp_bytes(paper_ks, one_chip, 8192, B)
+    large = _probe_temp_bytes(paper_ks, one_chip, 16384, B)
+    assert small == large
+    assert large <= B * KO.eval_lane_bytes(paper_ks.params)
 
 
 def test_fanout_probe_compiles(paper_ks, one_chip):
     """One step of the fan-out search over the whole hg38 column in 4
-    shards: 16 probed rows out of one shard's int64 sorted copy (8,606
-    of the column's 34,423 rows), then the eval of all 4 shards' rows on
-    the home chip against the batch's 16 trapdoor lanes."""
+    shards: 16 probed rows gathered out of one shard's s32 sorted copy
+    (8,606 of the column's 34,423 rows) and widened (`table.take_rows`),
+    which holds no more than the rows it reads, then the eval of all 4
+    shards' rows on the home chip against the batch's 16 trapdoor
+    lanes."""
     K, n = paper_ks.params.num_towers, paper_ks.params.n
     S, B, rows = 4, 16, 8606
-    gather = jax.jit(lambda c, p: c[p]).lower(
-        _spec(one_chip, rows, K, n), _spec(one_chip, B)).compile()
-    assert (gather.memory_analysis().temp_size_in_bytes
-            < V5E_HBM_BYTES // 4)
+    copy = _spec(one_chip, rows, K, n, dtype=jnp.int32)
+    gather = T.take_rows.lower(E.Ciphertext(copy, copy),
+                               _spec(one_chip, B)).compile()
+    assert gather.memory_analysis().temp_size_in_bytes <= B * K * n * 4
     X.jitted_eval(paper_ks).lower(
         E.Ciphertext(_spec(one_chip, S, B, K, n),
                      _spec(one_chip, S, B, K, n)),
